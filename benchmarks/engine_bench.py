@@ -10,25 +10,23 @@ engine can drift:
 * reference ``serve_engine`` FIFO tokens == the continuous Scheduler's
   greedy tokens on the same request set (the conformance contract of
   tests/test_engine.py, re-checked on the bench workload);
-* sharded == unsharded: a CHILD-MODE subprocess (``--child-sharded``)
-  re-runs the workload on 2 simulated CPU devices (tensor-parallel mesh
-  (1, 2)), asserts token equality against its own unsharded run, and
-  reports its per-call timings back as JSON — the subprocess is required
-  because XLA_FLAGS must be set before jax imports.
+* sharded == unsharded: the workload re-runs in process on a
+  tensor-parallel mesh (1, N) over all N visible devices and token
+  equality against the unsharded run is asserted. It needs N >= 2, and the
+  bench fails without them: on the CPU, start the process with
+  ``XLA_FLAGS=--xla_force_host_platform_device_count=2``.
 
 Rows land in ``BENCH_serving.json`` as an ``engine_*`` section via
 read-modify-write (the serving bench's workload header and rows are
 preserved; stale engine rows are replaced).
 
-  PYTHONPATH=src python -m benchmarks.engine_bench
-  (or benchmarks/run.py --sections engine)
+  XLA_FLAGS=--xla_force_host_platform_device_count=2 PYTHONPATH=src \
+      python -m benchmarks.engine_bench   (or benchmarks/run.py --sections engine)
 """
 from __future__ import annotations
 
 import json
 import os
-import subprocess
-import sys
 import time
 
 import numpy as np
@@ -43,7 +41,6 @@ QUOTA = 8
 WARMUP = 3
 N_CALLS = 20         # timed calls per op
 REPEATS = 3          # best mean-per-call wins (CPU wall jitter)
-SHARDED_DEVICES = 2
 
 
 def _build(dist=None):
@@ -159,37 +156,45 @@ def _parity_vs_scheduler(cfg, params, eng):
     return sum(len(r.tokens_out) for r in eng_reqs)
 
 
-def _child_sharded():
-    """Child mode: 2 simulated devices, sharded vs unsharded parity + the
-    sharded triad timings, reported as one JSON line on stdout."""
-    os.environ["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={SHARDED_DEVICES}")
+def _sharded_row():
+    """Sharded vs unsharded parity + the sharded triad timings over every
+    visible device (at least two)."""
     import jax
 
+    from repro.launch.mesh import make_serving_mesh
     from repro.parallel import make_dist
     from repro.runtime import serve_engine
 
-    assert len(jax.devices()) == SHARDED_DEVICES, jax.devices()
-    mesh = jax.make_mesh((1, SHARDED_DEVICES), ("data", "model"))
-    dist = make_dist(mesh)
-
-    cfg, params, eng_sh = _build(dist=dist)
+    n_dev = len(jax.devices())
+    cfg, params, eng_sh = _build(dist=make_dist(make_serving_mesh(n_dev)))
     _, _, eng_un = _build(dist=None)
     sh_reqs, un_reqs = _reqs(cfg, seed=4), _reqs(cfg, seed=4)
     serve_engine(eng_sh, sh_reqs)
     serve_engine(eng_un, un_reqs)
     toks_sh = [r.tokens_out for r in sh_reqs]
-    toks_un = [r.tokens_out for r in un_reqs]
-    assert toks_sh == toks_un, "sharded != unsharded greedy tokens"
-
-    us = _triad_timings(eng_sh, cfg, seed=5)
-    print(json.dumps({"parity": True, "devices": SHARDED_DEVICES,
-                      "tokens": sum(len(t) for t in toks_sh),
-                      "us_per_call": us,
-                      "trace_counts": eng_sh.trace_counts}))
+    assert toks_sh == [r.tokens_out for r in un_reqs], \
+        "sharded != unsharded greedy tokens"
+    row = {"name": "engine_sharded_generate",
+           "op": "generate",
+           "devices": n_dev,
+           "mesh": ["data", "model"],
+           "batch_slots": BATCH_SLOTS,
+           "max_len": MAX_LEN,
+           "sharded_equals_unsharded": True,
+           "parity_tokens": sum(len(t) for t in toks_sh),
+           "trace_counts": eng_sh.trace_counts}
+    for op, v in _triad_timings(eng_sh, cfg, seed=5).items():
+        row[f"{op}_us_per_call"] = round(v, 1)
+    return row
 
 
 def bench():
+    import jax
+    if len(jax.devices()) < 2:
+        raise RuntimeError(
+            "the sharded == unsharded parity needs at least two devices, "
+            "one is visible; on the CPU start the process with "
+            "XLA_FLAGS=--xla_force_host_platform_device_count=2")
     cfg, params, eng = _build()
     tokens = _parity_vs_scheduler(cfg, params, eng)
     us = _triad_timings(eng, cfg, seed=1)
@@ -210,30 +215,6 @@ def bench():
     rows[-1]["tokens_per_s"] = round(BATCH_SLOTS / (us["generate"] / 1e6), 1)
     rows.append(_sharded_row())
     return rows
-
-
-def _sharded_row():
-    env = dict(os.environ,
-               PYTHONPATH=os.path.abspath("src") + os.pathsep +
-               os.environ.get("PYTHONPATH", ""))
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.engine_bench", "--child-sharded"],
-        env=env, capture_output=True, text=True, timeout=900)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    child = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert child["parity"], "sharded parity assertion missing from child"
-    row = {"name": "engine_sharded_generate",
-           "op": "generate",
-           "devices": child["devices"],
-           "mesh": ["data", "model"],
-           "batch_slots": BATCH_SLOTS,
-           "max_len": MAX_LEN,
-           "sharded_equals_unsharded": True,
-           "parity_tokens": child["tokens"],
-           "trace_counts": child["trace_counts"]}
-    for op, v in child["us_per_call"].items():
-        row[f"{op}_us_per_call"] = round(v, 1)
-    return row
 
 
 def report(rows) -> str:
@@ -264,9 +245,6 @@ def write_json(rows, path=JSON_PATH):
 
 
 if __name__ == "__main__":
-    if "--child-sharded" in sys.argv:
-        _child_sharded()
-    else:
-        rows = bench()
-        print(report(rows))
-        print(f"# wrote {write_json(rows)}")
+    rows = bench()
+    print(report(rows))
+    print(f"# wrote {write_json(rows)}")

@@ -34,6 +34,7 @@ site.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, NamedTuple, Optional, Tuple
 
 import numpy as np
@@ -188,12 +189,18 @@ def pack_linear(w, wcfg: QuantizerConfig, num_groups: int,
             or wcfg.granularity != Granularity.PER_TENSOR:
         return None
     from repro.models.common import resolve_weight
-    w = resolve_weight(w).astype(jnp.float32)
+    w = resolve_weight(w)
     k_dim = w.shape[-2]
     if wcfg.bits == 4 and (k_dim % 2 or (k_dim // num_groups) % 2):
         return None
+    return _pack(w, perm, wcfg=wcfg, num_groups=num_groups)
 
+
+@functools.partial(jax.jit, static_argnames=("wcfg", "num_groups"))
+def _pack(w, perm, *, wcfg: QuantizerConfig, num_groups: int) -> dict:
+    """Jitted, so the f32 round trip never exists as eager temporaries."""
     def _pack_one(w2):
+        w2 = w2.astype(jnp.float32)
         if perm is not None:
             w2 = jnp.take(w2, perm, axis=0)
         qp = estimate_weight_params(w2, wcfg)
@@ -206,9 +213,37 @@ def pack_linear(w, wcfg: QuantizerConfig, num_groups: int,
             return {"q4": nibble.pack_rows(wq), "s": s, "colsum": colsum}
         return {"q": wq, "s": s, "colsum": colsum}
 
-    if w.ndim == 3:                      # stacked scan layout: per-layer pack
-        return jax.vmap(_pack_one)(w)
+    if w.ndim == 3:
+        # stacked scan layout: one layer at a time, so the f32 temporaries
+        # are one layer's, not the stack's
+        return jax.lax.map(_pack_one, w)
     return _pack_one(w)
+
+
+DEPLOY_LINEARS = {"attn": ("wq", "wk", "wv", "wo"),
+                  "ffn": ("w_gate", "w_up", "w_out", "w_in")}
+
+
+def count_packed(params) -> Tuple[int, int]:
+    """(packed, total) per-layer linears of a transformer param pytree over
+    :data:`DEPLOY_LINEARS` — stacked scan leaves count once per layer."""
+    packed = total = 0
+    blocks = list(params.get("layers", [])) + list(params.get("scan", [])) \
+        + list(params.get("tail", []))
+    for bp in blocks:
+        for part, names in DEPLOY_LINEARS.items():
+            sub = bp.get(part)
+            if not isinstance(sub, dict):
+                continue
+            for name in names:
+                if name not in sub:
+                    continue
+                w = sub[name]
+                leaf = jax.tree.leaves(w)[0]
+                per = leaf.shape[0] if leaf.ndim == 3 else 1
+                total += per
+                packed += per if is_packed(w) else 0
+    return packed, total
 
 
 def _site(act_state, policy, name) -> Optional[ActQuant]:

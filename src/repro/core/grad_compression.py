@@ -114,8 +114,7 @@ def make_crosspod_allreduce(mesh, grad_specs, *, group_size: int = 256,
         return avg, new_err
 
     def allreduce(grads, err):
-        from repro.core.jax_compat import shard_map
-        return shard_map(
+        return jax.shard_map(
             local_fn, mesh=mesh,
             in_specs=(grad_specs, err_specs),
             out_specs=(grad_specs, err_specs),

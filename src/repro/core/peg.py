@@ -42,8 +42,13 @@ class PEGSpec(NamedTuple):
 
 
 def _even_group_sizes(d: int, k: int, lane_align: bool) -> np.ndarray:
-    """K near-even group sizes summing to d; multiples of LANE if possible."""
-    if lane_align and d % LANE == 0 and (d // LANE) >= k:
+    """K near-even group sizes summing to d: equal multiples of LANE where
+    d allows, else equal sizes where k divides d. Equal sizes come first:
+    the deploy kernels take uniform groups (d_model 3840 in 4 groups is 4 x
+    960, not the lane-aligned 1024, 1024, 896, 896)."""
+    if lane_align and d % (LANE * k) == 0:
+        return np.full(k, d // k, dtype=np.int64)
+    if d % k and lane_align and d % LANE == 0 and (d // LANE) >= k:
         units = d // LANE
         base = units // k
         rem = units % k

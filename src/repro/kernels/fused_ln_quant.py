@@ -19,8 +19,12 @@ the range-based permutation already folded into gamma/beta and the adjacent
 weights, so groups are contiguous lane-aligned spans).
 
 Grid: (T / block_t,). Block: (block_t, d) — a full embedding row per token so
-the reduction stays in-block (d up to ~8k fits VMEM easily:
-256 x 8192 x 4B = 8 MiB).
+the reduction stays in-block. :func:`row_block` caps block_t so one f32 block
+stays within 1 MiB: the body holds several f32 temporaries of the block next
+to the double-buffered input and output, and all of it must fit the chip's
+scoped VMEM (at d 3840 a 256-row block does not on a TPU v5e; 64 rows do).
+The per-group scales live in SMEM and are broadcast to a (1, d) row with a
+lane-index select, G static (no lane-repeat, which Mosaic cannot lower).
 """
 from __future__ import annotations
 
@@ -29,6 +33,33 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+# f32 elements of one (block_t, d) block: 1 MiB
+_BLOCK_ELEMS = 1 << 18
+
+
+def row_block(block_t: int, d: int) -> int:
+    """block_t capped so a (rows, d) f32 block stays within 1 MiB (a power
+    of two, at least 8 rows)."""
+    rows = block_t
+    while rows > 8 and rows * d > _BLOCK_ELEMS:
+        rows //= 2
+    return rows
+
+
+def group_row(ref, d: int):
+    """(G,) SMEM scalars -> (1, d) f32 row: group g's value on its
+    contiguous span of d // G lanes."""
+    g = ref.shape[0]
+    row = jnp.full((1, d), ref[0], jnp.float32)
+    if g > 1:
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, d), 1)
+        for i in range(1, g):
+            row = jnp.where(col >= i * (d // g), ref[i], row)
+    return row
 
 
 def _norm_quant_kernel(g_ref, b_ref, s_ref, z_ref, x_ref, o_ref, *,
@@ -42,9 +73,8 @@ def _norm_quant_kernel(g_ref, b_ref, s_ref, z_ref, x_ref, o_ref, *,
         var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
         y = x * jax.lax.rsqrt(var + eps) * (1.0 + g_ref[...])
     d = x.shape[-1]
-    g = s_ref.shape[0]
-    s = jnp.repeat(s_ref[...], d // g)[None, :]
-    z = jnp.repeat(z_ref[...], d // g)[None, :]
+    s = group_row(s_ref, d)
+    z = group_row(z_ref, d)
     q = jnp.clip(jnp.round(y / s) + z, qmin, qmax)
     if emit:
         o_ref[...] = q.astype(o_ref.dtype)
@@ -55,7 +85,7 @@ def _norm_quant_kernel(g_ref, b_ref, s_ref, z_ref, x_ref, o_ref, *,
 def _call(x, gamma, beta, scale, zp, *, kind, emit, qmin, qmax, eps,
           out_dtype, block_t, interpret):
     t, d = x.shape
-    bt = min(block_t, t)
+    bt = min(row_block(block_t, d), t)
     assert t % bt == 0
     scale = jnp.atleast_1d(jnp.asarray(scale, jnp.float32))
     zp = jnp.atleast_1d(jnp.asarray(zp, jnp.float32))
@@ -70,15 +100,16 @@ def _call(x, gamma, beta, scale, zp, *, kind, emit, qmin, qmax, eps,
         out_shape=jax.ShapeDtypeStruct((t, d), out_dtype),
         grid=(t // bt,),
         in_specs=[
-            pl.BlockSpec((d,), lambda i: (0,)),
-            pl.BlockSpec((d,), lambda i: (0,)),
-            pl.BlockSpec((g,), lambda i: (0,)),
-            pl.BlockSpec((g,), lambda i: (0,)),
+            pl.BlockSpec((1, d), lambda i: (0, 0)),
+            pl.BlockSpec((1, d), lambda i: (0, 0)),
+            SMEM,
+            SMEM,
             pl.BlockSpec((bt, d), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((bt, d), lambda i: (i, 0)),
         interpret=interpret,
-    )(gamma.astype(jnp.float32), beta.astype(jnp.float32), scale, zp, x)
+    )(gamma.astype(jnp.float32).reshape(1, d),
+      beta.astype(jnp.float32).reshape(1, d), scale, zp, x)
 
 
 def ln_fake_quant(x, gamma, beta, scale, zp, *, qmin: int, qmax: int,

@@ -67,13 +67,20 @@ def pack_nibbles(x, axis: int = -1):
     return _pack_pair(lo, hi)
 
 
+def unpack_halves(packed):
+    """Split-half packed int8 -> its (low, high) int8 halves of int4 values
+    (cells ``[0, ceil(n/2))`` and ``[ceil(n/2), n)`` plus the odd-``n`` zero
+    pad). The decode kernels consume the halves as they are, so no lane
+    concatenate at the unaligned half boundary is ever emitted."""
+    b = jnp.asarray(packed).astype(jnp.int32)
+    return (_sext4(b).astype(jnp.int8),
+            _sext4(jnp.right_shift(b, 4)).astype(jnp.int8))
+
+
 def unpack_nibbles(packed, n: int, axis: int = -1):
     """Inverse of :func:`pack_nibbles`: packed int8 -> int8 array of int4
     values with the original length ``n`` along ``axis``."""
-    b = jnp.asarray(packed).astype(jnp.int32)
-    lo = _sext4(b)
-    hi = _sext4(jnp.right_shift(b, 4))
-    out = jnp.concatenate([lo, hi], axis=axis).astype(jnp.int8)
+    out = jnp.concatenate(unpack_halves(packed), axis=axis)
     axis = axis % out.ndim
     if out.shape[axis] != n:
         out = jnp.take(out, jnp.arange(n), axis=axis)

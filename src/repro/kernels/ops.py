@@ -1,8 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) kernels run in interpret mode — the kernel body
-executes in Python for correctness validation; on TPU the same call lowers to
-Mosaic. ``interpret=None`` auto-detects.
+On the CPU backend kernels run in interpret mode — the kernel body executes
+in Python for correctness validation; on a TPU the same call lowers to
+Mosaic and is never interpreted. ``interpret=None`` picks by backend; an
+explicit ``False`` on the CPU lowers for a described (not attached) TPU.
 
 Two conventions enforced here (and relied on by repro.core.deploy):
 
@@ -34,9 +35,13 @@ from repro.kernels import ref as _ref
 
 
 def _interp(flag: Optional[bool]) -> bool:
-    if flag is None:
-        return jax.default_backend() != "tpu"
-    return flag
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend != "cpu":
+        raise RuntimeError(f"Pallas kernels target the TPU (Mosaic) or the "
+                           f"CPU interpreter, not {backend!r}")
+    return True if flag is None else flag
 
 
 def _flatten_rows(x, block: int):
@@ -45,9 +50,11 @@ def _flatten_rows(x, block: int):
     d = x.shape[-1]
     x2 = x.reshape(-1, d)
     m = x2.shape[0]
-    # m <= block runs as one partial block (bm == m); larger ragged row
-    # counts are zero-padded to a block multiple and sliced back after.
-    pad = (-m) % block if m > block else 0
+    # m <= block runs as one partial block (bm == m) of whole sublane
+    # tiles (Mosaic refuses some broadcasts over fewer than 8 rows); larger
+    # ragged row counts are zero-padded to a block multiple. Padding rows
+    # are sliced off after.
+    pad = (-m) % block if m > block else (-m) % 8
     if pad:
         x2 = jnp.pad(x2, ((0, pad), (0, 0)))
     return x2, lead, m
@@ -65,7 +72,7 @@ def _unflatten_rows(y, lead, m):
                                              "interpret"))
 def peg_fake_quant(x, scales, zps, *, qmin: int = 0, qmax: int = 255,
                    block_t: int = 256, interpret: Optional[bool] = None):
-    x2, lead, m = _flatten_rows(x, block_t)
+    x2, lead, m = _flatten_rows(x, _lnq.row_block(block_t, x.shape[-1]))
     out = _peg.peg_fake_quant(x2, scales, zps, qmin=qmin, qmax=qmax,
                               block_t=block_t, interpret=_interp(interpret))
     return _unflatten_rows(out, lead, m)
@@ -75,7 +82,7 @@ def peg_fake_quant(x, scales, zps, *, qmin: int = 0, qmax: int = 255,
                                              "interpret"))
 def peg_quantize(x, scales, zps, *, qmin: int = 0, qmax: int = 255,
                  block_t: int = 256, interpret: Optional[bool] = None):
-    x2, lead, m = _flatten_rows(x, block_t)
+    x2, lead, m = _flatten_rows(x, _lnq.row_block(block_t, x.shape[-1]))
     out = _peg.peg_quantize(x2, scales, zps, qmin=qmin, qmax=qmax,
                             block_t=block_t, interpret=_interp(interpret))
     return _unflatten_rows(out, lead, m)
@@ -120,13 +127,13 @@ def int8_matmul(a_q, w_q, *, s_a, s_w, z_a=None, w_colsum=None, bias=None,
 
 
 @functools.partial(jax.jit, static_argnames=("activation", "qmin", "qmax",
-                                             "block_m", "block_n", "w_bits",
-                                             "interpret"))
+                                             "block_m", "block_n", "block_k",
+                                             "w_bits", "interpret"))
 def int8_matmul_peg(a_q, w_q, act_scales, act_zps, *, w_scale,
                     w_colsum=None, bias=None, mul=None,
                     activation: str = "none", out_scale=None, out_zp=None,
                     qmin: int = -128, qmax: int = 127, block_m: int = 256,
-                    block_n: int = 256, w_bits: int = 8,
+                    block_n: int = 256, block_k: int = 512, w_bits: int = 8,
                     interpret: Optional[bool] = None):
     """PEG fixed-point matmul: K re-scalings fused into the MXU k-loop.
     Computes the zero-point correction internally unless ``w_colsum`` (G, N)
@@ -146,7 +153,8 @@ def int8_matmul_peg(a_q, w_q, act_scales, act_zps, *, w_scale,
                                activation=activation, out_scale=out_scale,
                                out_zp=out_zp, qmin=qmin, qmax=qmax,
                                block_m=block_m, block_n=block_n,
-                               w_bits=w_bits, interpret=_interp(interpret))
+                               block_k=block_k, w_bits=w_bits,
+                               interpret=_interp(interpret))
     return _unflatten_rows(out, lead, m)
 
 
@@ -288,7 +296,7 @@ def paged_int8_attend_decode(q_q, q_scale, k_arena, k_scale, v_arena,
 def ln_fake_quant(x, gamma, beta, scale, zp, *, qmin: int = 0,
                   qmax: int = 255, eps: float = 1e-6, block_t: int = 256,
                   interpret: Optional[bool] = None):
-    x2, lead, m = _flatten_rows(x, block_t)
+    x2, lead, m = _flatten_rows(x, _lnq.row_block(block_t, x.shape[-1]))
     out = _lnq.ln_fake_quant(x2, gamma, beta, scale, zp, qmin=qmin,
                              qmax=qmax, eps=eps, block_t=block_t,
                              interpret=_interp(interpret))
@@ -300,7 +308,7 @@ def ln_fake_quant(x, gamma, beta, scale, zp, *, qmin: int = 0,
 def ln_quantize(x, gamma, beta, scale, zp, *, qmin: int = 0, qmax: int = 255,
                 eps: float = 1e-6, block_t: int = 256,
                 interpret: Optional[bool] = None):
-    x2, lead, m = _flatten_rows(x, block_t)
+    x2, lead, m = _flatten_rows(x, _lnq.row_block(block_t, x.shape[-1]))
     out = _lnq.ln_quantize(x2, gamma, beta, scale, zp, qmin=qmin, qmax=qmax,
                            eps=eps, block_t=block_t,
                            interpret=_interp(interpret))
@@ -312,7 +320,7 @@ def ln_quantize(x, gamma, beta, scale, zp, *, qmin: int = 0, qmax: int = 255,
 def rms_fake_quant(x, gamma, scale, zp, *, qmin: int = 0, qmax: int = 255,
                    eps: float = 1e-6, block_t: int = 256,
                    interpret: Optional[bool] = None):
-    x2, lead, m = _flatten_rows(x, block_t)
+    x2, lead, m = _flatten_rows(x, _lnq.row_block(block_t, x.shape[-1]))
     out = _lnq.rms_fake_quant(x2, gamma, scale, zp, qmin=qmin, qmax=qmax,
                               eps=eps, block_t=block_t,
                               interpret=_interp(interpret))
@@ -324,7 +332,7 @@ def rms_fake_quant(x, gamma, scale, zp, *, qmin: int = 0, qmax: int = 255,
 def rms_quantize(x, gamma, scale, zp, *, qmin: int = 0, qmax: int = 255,
                  eps: float = 1e-6, block_t: int = 256,
                  interpret: Optional[bool] = None):
-    x2, lead, m = _flatten_rows(x, block_t)
+    x2, lead, m = _flatten_rows(x, _lnq.row_block(block_t, x.shape[-1]))
     out = _lnq.rms_quantize(x2, gamma, scale, zp, qmin=qmin, qmax=qmax,
                             eps=eps, block_t=block_t,
                             interpret=_interp(interpret))

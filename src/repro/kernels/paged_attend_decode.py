@@ -40,12 +40,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.nibble import unpack_nibbles
+from repro.kernels.int8_attend_decode import (SMEM, decode_attend_step,
+                                              decode_scratch, merge_parts,
+                                              split_queries)
+from repro.kernels.nibble import packed_len
 
-NEG_INF = -1e30
 
-
-def _paged_kernel(*refs, nb: int, bs: int, s_cap: int,
+def _paged_kernel(*refs, nb: int, bs: int, s_cap: int, hd: int,
                   window: Optional[int], logit_softcap: Optional[float],
                   quantized: bool, has_smq: bool, has_smo: bool,
                   sm_qmin: int, sm_qmax: int, smo_qmin: int, smo_qmax: int,
@@ -53,58 +54,21 @@ def _paged_kernel(*refs, nb: int, bs: int, s_cap: int,
     refs = list(refs)
     tbl_ref = refs.pop(0)                   # (B, nb) scalar-prefetch
     qp_ref = refs.pop(0)                    # (B,)   scalar-prefetch
+    kz_ref = vz_ref = qs_ref = qz_ref = ks_ref = vs_ref = None
+    if quantized:
+        kz_ref = refs.pop(0)                # (B, KV) SMEM
+        vz_ref = refs.pop(0)
     smq_ref = refs.pop(0) if has_smq else None
     smo_ref = refs.pop(0) if has_smo else None
     if quantized:
-        (q_ref, qs_ref, qz_ref, kz_ref, vz_ref, k_ref, ks_ref, v_ref,
-         vs_ref, o_ref, m_ref, l_ref, acc_ref) = refs
+        (q_ref, qs_ref, qz_ref, k_ref, ks_ref, v_ref, vs_ref,
+         o_ref, m_ref, l_ref, acc_ref) = refs
     else:
         (q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref) = refs
 
     b = pl.program_id(0)
-    kk = pl.program_id(2)
+    kk = pl.program_id(1)
     blk = jax.lax.rem(kk, nb)               # logical block (2-pass folds)
-
-    @pl.when(kk == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    # logits for this block (recomputed in the second pass when two-pass)
-    k = k_ref[0, :, 0, :]                              # (bs, hd[/2])
-    if quantized:
-        q = q_ref[0, 0]                                # (G, hd) int8
-        hd = q.shape[-1]
-        if kv_bits == 4:
-            # nibble extract in VMEM before the MXU q.k^T; the rowsum /
-            # colsum corrections below see the unpacked int4 values
-            k = unpack_nibbles(k, hd)
-        s32 = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                  preferred_element_type=jnp.int32)
-        # zero-point corrections, identical to int8_attend_decode:
-        #   sum (q - zq)(k - zk) = q.k - zq colsum(k) - zk rowsum(q)
-        #                          + hd zq zk
-        zq = qz_ref[0, 0][:, None]                     # (G, 1)
-        zk = kz_ref[0, 0]                              # scalar (this head)
-        kcol = jnp.sum(k.astype(jnp.int32), axis=-1).astype(jnp.float32)
-        qrow = jnp.sum(q.astype(jnp.int32), axis=-1).astype(jnp.float32)
-        acc32 = (s32.astype(jnp.float32) - zq * kcol[None, :]
-                 - zk * qrow[:, None] + hd * zq * zk)
-        s = (acc32 * qs_ref[0, 0][:, None]
-             * ks_ref[0, :, 0][None, :])               # (G, bs)
-    else:
-        q = q_ref[0, 0].astype(jnp.float32)            # (G, hd), scale folded
-        s = jax.lax.dot_general(q, k.astype(jnp.float32),
-                                (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-    if logit_softcap is not None:
-        s = logit_softcap * jnp.tanh(s / logit_softcap)
-    if has_smq:
-        sm_s = smq_ref[0]
-        sm_z = smq_ref[1]
-        sq = jnp.clip(jnp.round(s / sm_s) + sm_z, sm_qmin, sm_qmax)
-        s = (sq - sm_z) * sm_s
 
     # derived positions: cell L of the logical view holds position
     # q_pos - ((q_pos - L) mod S) — exact for written cells, invalid
@@ -116,95 +80,54 @@ def _paged_kernel(*refs, nb: int, bs: int, s_cap: int,
     valid = (L < s_cap) & (p >= 0) & (tbl_ref[b, blk] >= 0)
     if window is not None:
         valid &= p > qp - window
-    s = jnp.where(valid, s, NEG_INF)                   # (1,bs) -> (G,bs)
-
-    def _pv(pmat):
-        """p @ V with the variant's dequant: per-slot v scales + static
-        zero-point row correction for int8, plain f32 for bf16."""
-        vblk = v_ref[0, :, 0, :]
-        if quantized and kv_bits == 4:
-            vblk = unpack_nibbles(vblk, q_ref.shape[-1])
-        vblk = vblk.astype(jnp.float32)
-        if quantized:
-            pv = pmat * vs_ref[0, :, 0][None, :]
-            zv = vz_ref[0, 0]
-            return (jax.lax.dot_general(pv, vblk, (((1,), (0,)), ((), ())))
-                    - zv * jnp.sum(pv, axis=-1)[:, None])
-        return jax.lax.dot_general(pmat, vblk, (((1,), (0,)), ((), ())))
-
-    @pl.when(kk < nb)
-    def _stats_pass():
-        m_prev = m_ref[:, 0]
-        m_new = jnp.maximum(jnp.maximum(m_prev, jnp.max(s, axis=-1)),
-                            NEG_INF)
-        pmat = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m_prev - m_new)
-        m_ref[:, 0] = m_new
-        l_ref[:, 0] = l_ref[:, 0] * corr + jnp.sum(pmat, axis=-1)
-        if not has_smo:
-            acc_ref[...] = acc_ref[...] * corr[:, None] + _pv(pmat)
-
-    if has_smo:
-        @pl.when(kk >= nb)
-        def _emit_pass():
-            # second pass: (m, l) final — quantize the normalized probs on
-            # the softmax_out grid (not renormalized, as in simulate).
-            pmat = jnp.exp(s - m_ref[:, 0][:, None]) / \
-                jnp.maximum(l_ref[:, 0], 1e-30)[:, None]
-            so_s = smo_ref[0]
-            so_z = smo_ref[1]
-            pq = jnp.clip(jnp.round(pmat / so_s) + so_z, smo_qmin, smo_qmax)
-            pmat = (pq - so_z) * so_s
-            acc_ref[...] += _pv(pmat)
-
-        @pl.when(kk == 2 * nb - 1)
-        def _done_two_pass():
-            o_ref[0, 0] = acc_ref[...]
-    else:
-        @pl.when(kk == nb - 1)
-        def _done():
-            o_ref[0, 0] = acc_ref[...] / \
-                jnp.maximum(l_ref[:, 0], 1e-30)[:, None]
+    decode_attend_step(
+        step=kk, n_blocks=nb, lane=b, valid=valid, q_ref=q_ref, k_ref=k_ref,
+        v_ref=v_ref, o_ref=o_ref, m_ref=m_ref, l_ref=l_ref, acc_ref=acc_ref,
+        hd=hd, quantized=quantized, kv_bits=kv_bits,
+        logit_softcap=logit_softcap, smq_ref=smq_ref, smo_ref=smo_ref,
+        sm_qmin=sm_qmin, sm_qmax=sm_qmax, smo_qmin=smo_qmin,
+        smo_qmax=smo_qmax, qs_ref=qs_ref, qz_ref=qz_ref, kz_ref=kz_ref,
+        vz_ref=vz_ref, ks_ref=ks_ref, vs_ref=vs_ref)
 
 
 def _paged_call(kernel_operands, in_specs, *, b, kv, g, hd, nb, bs, s_cap,
                 window, logit_softcap, quantized, sm_quant, smo_quant,
                 sm_qmin, sm_qmax, smo_qmin, smo_qmax, block_table, q_pos,
-                kv_bits=8, interpret=False):
+                kv_bits=8, interpret=False, zero_points=()):
     has_smq = sm_quant is not None
     has_smo = smo_quant is not None
     n_steps = 2 * nb if has_smo else nb
-    operands = []
-    specs = []
+    operands = list(zero_points)
+    specs = [SMEM] * len(zero_points)
     if has_smq:
         operands.append(sm_quant.astype(jnp.float32))
-        specs.append(pl.BlockSpec((2,), lambda i, j, kk, tbl, qp: (0,)))
+        specs.append(SMEM)
     if has_smo:
         operands.append(smo_quant.astype(jnp.float32))
-        specs.append(pl.BlockSpec((2,), lambda i, j, kk, tbl, qp: (0,)))
+        specs.append(SMEM)
     operands += kernel_operands
     specs += in_specs
+    parts, w = kernel_operands[0].shape[2], kernel_operands[0].shape[-1]
     kernel = functools.partial(
-        _paged_kernel, nb=nb, bs=bs, s_cap=s_cap, window=window,
+        _paged_kernel, nb=nb, bs=bs, s_cap=s_cap, hd=hd, window=window,
         logit_softcap=logit_softcap, quantized=quantized, has_smq=has_smq,
         has_smo=has_smo, sm_qmin=sm_qmin, sm_qmax=sm_qmax,
         smo_qmin=smo_qmin, smo_qmax=smo_qmax, kv_bits=kv_bits)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kv, n_steps),
+        grid=(b, n_steps),
         in_specs=specs,
-        out_specs=pl.BlockSpec((1, 1, g, hd),
-                               lambda i, j, kk, tbl, qp: (i, j, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((g, 1), jnp.float32),   # running max
-                        pltpu.VMEM((g, 1), jnp.float32),   # running denom
-                        pltpu.VMEM((g, hd), jnp.float32)])  # numerator
-    return pl.pallas_call(
+        out_specs=pl.BlockSpec((1, kv, parts, g, w),
+                               lambda i, kk, tbl, qp: (i, 0, 0, 0, 0)),
+        scratch_shapes=decode_scratch(kv, parts, g, w))
+    out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((b, kv, g, hd), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, kv, parts, g, w), jnp.float32),
         grid_spec=grid_spec,
         interpret=interpret,
     )(jnp.asarray(block_table, jnp.int32), jnp.asarray(q_pos, jnp.int32),
       *operands)
+    return merge_parts(out, hd)
 
 
 def _arena_maps(nb, has_smo):
@@ -212,19 +135,24 @@ def _arena_maps(nb, has_smo):
     the two-pass schedule re-walks K while V pins to the first block during
     the stats pass (fetched once per program there), exactly as in
     int8_attend_decode. Unmapped (-1) entries clip to block 0 — their cells
-    all derive invalid, so the garbage is masked."""
+    all derive invalid, so the garbage is masked. Payload blocks are
+    (1, bs, KV, hd) over the (N, bs, KV, hd) arena; scale blocks
+    (1, KV, bs) over the scales handed over as (N, KV, bs)."""
     if has_smo:
         ck = lambda kk: jax.lax.rem(kk, nb)
         cv = lambda kk: jnp.maximum(kk - nb, 0)
     else:
         ck = cv = lambda kk: kk
-    k_map = lambda i, j, kk, tbl, qp: (jnp.maximum(tbl[i, ck(kk)], 0),
-                                       0, j, 0)
-    v_map = lambda i, j, kk, tbl, qp: (jnp.maximum(tbl[i, cv(kk)], 0),
-                                       0, j, 0)
-    ks_map = lambda i, j, kk, tbl, qp: (jnp.maximum(tbl[i, ck(kk)], 0), 0, j)
-    vs_map = lambda i, j, kk, tbl, qp: (jnp.maximum(tbl[i, cv(kk)], 0), 0, j)
+    k_map = lambda i, kk, tbl, qp: (jnp.maximum(tbl[i, ck(kk)], 0), 0, 0, 0)
+    v_map = lambda i, kk, tbl, qp: (jnp.maximum(tbl[i, cv(kk)], 0), 0, 0, 0)
+    ks_map = lambda i, kk, tbl, qp: (jnp.maximum(tbl[i, ck(kk)], 0), 0, 0)
+    vs_map = lambda i, kk, tbl, qp: (jnp.maximum(tbl[i, cv(kk)], 0), 0, 0)
     return k_map, v_map, ks_map, vs_map
+
+
+def _q_spec(kv, parts, g, w):
+    return pl.BlockSpec((1, kv, parts, g, w),
+                        lambda i, kk, tbl, qp: (i, 0, 0, 0, 0))
 
 
 def paged_attend_decode(q: jnp.ndarray, k_arena: jnp.ndarray,
@@ -251,12 +179,12 @@ def paged_attend_decode(q: jnp.ndarray, k_arena: jnp.ndarray,
     nb = block_table.shape[1]
     assert nb * bs >= s_cap, f"table covers {nb * bs} < s_cap={s_cap}"
     k_map, v_map, _, _ = _arena_maps(nb, smo_quant is not None)
-    operands = [q.astype(jnp.float32), k_arena, v_arena]
+    q_parts = split_queries(q.astype(jnp.float32), 8)
+    operands = [q_parts, k_arena, v_arena]
     in_specs = [
-        pl.BlockSpec((1, 1, g, hd),
-                     lambda i, j, kk, tbl, qp: (i, j, 0, 0)),      # q
-        pl.BlockSpec((1, bs, 1, hd), k_map),                       # k arena
-        pl.BlockSpec((1, bs, 1, hd), v_map),                       # v arena
+        _q_spec(kv, 1, g, hd),                                     # q
+        pl.BlockSpec((1, bs, kv, hd), k_map),                      # k arena
+        pl.BlockSpec((1, bs, kv, hd), v_map),                      # v arena
     ]
     return _paged_call(
         operands, in_specs, b=b, kv=kv, g=g, hd=hd, nb=nb, bs=bs,
@@ -295,31 +223,28 @@ def paged_int8_attend_decode(q_q: jnp.ndarray, q_scale: jnp.ndarray,
     per block. Returns (B, KV, G, hd) f32.
     """
     b, kv, g, hd = q_q.shape
-    hd_kv = hd
-    if kv_bits == 4:
-        assert hd % 2 == 0, f"kv_bits=4 needs even head_dim, got {hd}"
-        hd_kv = hd // 2
-        assert k_arena.shape[-1] == hd_kv, (
-            f"packed arena last dim {k_arena.shape[-1]} != hd/2 = {hd_kv}")
+    hd_kv = packed_len(hd) if kv_bits == 4 else hd
+    assert k_arena.shape[-1] == hd_kv, (
+        f"arena last dim {k_arena.shape[-1]} != {hd_kv}")
     bs = k_arena.shape[1]
     nb = block_table.shape[1]
     assert nb * bs >= s_cap, f"table covers {nb * bs} < s_cap={s_cap}"
     k_map, v_map, ks_map, vs_map = _arena_maps(nb, smo_quant is not None)
-    operands = [q_q, q_scale.astype(jnp.float32), q_zp.astype(jnp.float32),
-                k_zp.astype(jnp.float32), v_zp.astype(jnp.float32),
-                k_arena, k_scale.astype(jnp.float32), v_arena,
-                v_scale.astype(jnp.float32)]
+    q_parts = split_queries(q_q, kv_bits)
+    parts, w = q_parts.shape[2], q_parts.shape[-1]
+    operands = [q_parts, q_scale.astype(jnp.float32)[..., None],
+                q_zp.astype(jnp.float32)[..., None], k_arena,
+                jnp.swapaxes(k_scale.astype(jnp.float32), 1, 2), v_arena,
+                jnp.swapaxes(v_scale.astype(jnp.float32), 1, 2)]
+    qv_map = lambda i, kk, tbl, qp: (i, 0, 0, 0)
     in_specs = [
-        pl.BlockSpec((1, 1, g, hd),
-                     lambda i, j, kk, tbl, qp: (i, j, 0, 0)),      # q_q
-        pl.BlockSpec((1, 1, g), lambda i, j, kk, tbl, qp: (i, j, 0)),  # q_s
-        pl.BlockSpec((1, 1, g), lambda i, j, kk, tbl, qp: (i, j, 0)),  # q_z
-        pl.BlockSpec((1, 1), lambda i, j, kk, tbl, qp: (i, j)),        # k_z
-        pl.BlockSpec((1, 1), lambda i, j, kk, tbl, qp: (i, j)),        # v_z
-        pl.BlockSpec((1, bs, 1, hd_kv), k_map),                    # k arena
-        pl.BlockSpec((1, bs, 1), ks_map),                          # k scales
-        pl.BlockSpec((1, bs, 1, hd_kv), v_map),                    # v arena
-        pl.BlockSpec((1, bs, 1), vs_map),                          # v scales
+        _q_spec(kv, parts, g, w),                                  # q_q
+        pl.BlockSpec((1, kv, g, 1), qv_map),                       # q_s
+        pl.BlockSpec((1, kv, g, 1), qv_map),                       # q_z
+        pl.BlockSpec((1, bs, kv, hd_kv), k_map),                   # k arena
+        pl.BlockSpec((1, kv, bs), ks_map),                         # k scales
+        pl.BlockSpec((1, bs, kv, hd_kv), v_map),                   # v arena
+        pl.BlockSpec((1, kv, bs), vs_map),                         # v scales
     ]
     return _paged_call(
         operands, in_specs, b=b, kv=kv, g=g, hd=hd, nb=nb, bs=bs,
@@ -327,4 +252,5 @@ def paged_int8_attend_decode(q_q: jnp.ndarray, q_scale: jnp.ndarray,
         quantized=True, sm_quant=sm_quant, smo_quant=smo_quant,
         sm_qmin=sm_qmin, sm_qmax=sm_qmax, smo_qmin=smo_qmin,
         smo_qmax=smo_qmax, block_table=block_table, q_pos=q_pos,
-        kv_bits=kv_bits, interpret=interpret)
+        kv_bits=kv_bits, interpret=interpret,
+        zero_points=(k_zp.astype(jnp.float32), v_zp.astype(jnp.float32)))

@@ -67,6 +67,14 @@ and verifies shared == unshared greedy tokens — in particular under
 their per-head per-slot scales inside the block and sharing stays
 bit-exact.
 
+Without ``--reduced`` the model runs at its published widths in bf16 on a
+``(1, --tp)`` (data, model) mesh over the local devices. Every parity check
+gates the run: a missed tolerance exits non-zero, and each tolerance
+states its reason. ``--verify`` also checks what was served: every request
+got its tokens, and the served requests' cached prefill + decode logits,
+replayed through the same jitted steps with the served params, match an
+un-cached forward. References run at ``highest`` matmul precision.
+
 CPU smoke:
   PYTHONPATH=src python -m repro.launch.serve --arch gemma2-2b --reduced \
       --requests 8 --new-tokens 8 [--quantize [--deploy-int8 [--kv-bits 8]]] \
@@ -76,15 +84,17 @@ CPU smoke:
 from __future__ import annotations
 
 import argparse
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
-from repro.core import Mode, QuantCtx, w8a8_policy
+from repro.core import Mode, QuantCtx
 from repro.core.pipeline import ptq
-from repro.launch.mesh import make_production_mesh
+from repro.launch.compile_cache import use_compile_cache
+from repro.launch.mesh import make_serving_mesh
 from repro.models import transformer as tfm
 from repro.parallel import make_dist, make_param_shardings
 from repro.runtime import Request, serve
@@ -98,7 +108,6 @@ def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
-    ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--new-tokens", type=int, default=8)
@@ -207,15 +216,410 @@ def build_parser() -> argparse.ArgumentParser:
                          "--prefix-cache/--over-commit and the telemetry "
                          "flags)")
     ap.add_argument("--tp", type=int, default=1, metavar="N",
-                    help="shard the engine tensor-parallel over N devices "
-                         "(jax.sharding mesh (1, N) over (data, model); "
-                         "admission stays host-local, the admit mask "
-                         "broadcasts replicated). On CPU, simulate "
+                    help="shard the engine tensor-parallel over N local "
+                         "devices (jax.sharding mesh (1, N) over (data, "
+                         "model); admission stays host-local, the admit "
+                         "mask broadcasts replicated). On CPU, simulate "
                          "devices with XLA_FLAGS="
                          "--xla_force_host_platform_device_count=N "
-                         "(requires --reduced; 1 = unsharded)")
+                         "(with --reduced, 1 = unsharded)")
+    ap.add_argument("--warmup", action="store_true",
+                    help="serve the workload once before the measured run, "
+                         "so its wall time and tokens/s exclude "
+                         "compilation")
+    ap.add_argument("--verify", action="store_true",
+                    help="check the served output: every request got its "
+                         "tokens, and the served requests' cached prefill "
+                         "+ decode logits (replayed through the serving "
+                         "steps) match an un-cached forward within a "
+                         "stated tolerance (exits non-zero otherwise); "
+                         "greedy agreement with the reference is printed")
     ap.add_argument("--seed", type=int, default=0)
     return ap
+
+
+# Parity tolerances, on the relative RMS error of the logits,
+# ||logits - reference|| / ||reference|| (the max |error| over max
+# |reference| is printed beside it: one flipped code of the 8-bit logits
+# grid alone moves that by ~1/128, so it is reported, not gated).
+# f32 (--reduced): the paths differ only in summation order and in the odd
+# requantization tie an order change moves by one int8 code.
+TOL_F32 = 1e-3
+# bf16 (full width): activations between ops are bf16, whose ulp is 2^-8
+# (0.4% relative); an order-of-summation difference flips single roundings,
+# and the flips compound through the residual stream of every layer
+# (cached vs un-cached over 24 layers: 1.6e-2 on a TPU v5e).
+TOL_BF16 = 5e-2
+# quantized vs float KV cache, any width: the cache stores values already
+# on the calibrated k/v grids and reads them back exactly, so both runs
+# attend over the same f32 values and read 0 (CPU and TPU v5e). Any error
+# fails: k written on the v grid reads 0.23 on a TPU v5e, and so would one
+# requantization tie landing between the two attention paths (4.1e-2 with
+# the PEG scales shifted), which the seeded traffic does not hit.
+TOL_KV8 = 1e-6
+# quantized model at full width. Two implementations of one site (Mosaic
+# vs XLA, chunked vs whole-sequence attention) round a few of ~10^6 values
+# to the other side of a tie (10 of 7.9M norm+quantize codes of one layer
+# on a TPU v5e), and every later 8-bit site requantizes the difference
+# into whole grid steps: one code per sequence moved at the first site
+# moves the logits by 8.4e-2 over 2 layers and 0.23 over 24. The clean
+# readings sit below that (int8 vs fake-quant 4.5e-2, served 7.4e-2);
+# the planted faults of benchmarks/quant_floor.py above it (PEG
+# permutation off by one channel 1.34, k cache on the v grid 0.36 served).
+# int8 kernels vs the fake-quant reference, first REF_SUPERS layers:
+TOL_INT8 = 0.15
+# the served program's cached prefill + decode vs its un-cached forward:
+TOL_SERVED = 0.15
+# depth of the deploy parity references: the fake-quant reference needs
+# float weights, and only these first super-blocks' are kept once the rest
+# is packed to int8
+REF_SUPERS = 2
+
+
+def rel_errors(ref, got) -> tuple:
+    """(relative RMS error, max |error| over max |ref|) of ``got``."""
+    ref = np.asarray(ref, np.float64)
+    err = np.asarray(got, np.float64) - ref
+    return (float(np.sqrt(np.sum(err ** 2) / (np.sum(ref ** 2) + 1e-30))),
+            float(np.max(np.abs(err)) / (np.max(np.abs(ref)) + 1e-30)))
+
+
+def gate(tag: str, what: str, errors: tuple, tol: float) -> None:
+    """Print one parity measurement (``rel_errors``); exit non-zero when
+    its relative RMS error passes the tolerance."""
+    rms, mx = errors
+    ok = rms <= tol
+    print(f"[{tag}] {what}: rel rms logits error {rms:.3e} (tolerance "
+          f"{tol:g}) {'OK' if ok else 'FAIL'}; max rel {mx:.3e}")
+    if not ok:
+        raise SystemExit(f"[{tag}] FAIL: {what}: rel rms logits error "
+                         f"{rms:.3e} > tolerance {tol:g}")
+
+
+def _f32(tree):
+    """Float leaves as f32; packed integer payloads unchanged."""
+    return jax.tree.map(lambda x: x.astype(jnp.float32)
+                        if jnp.issubdtype(x.dtype, jnp.floating) else x, tree)
+
+
+def first_supers(params, n: int):
+    """The first ``n`` super-blocks of a stacked param pytree (a copy) —
+    the params of ``cfg.with_supers(n)``."""
+    out = {k: v for k, v in params.items() if k != "scan"}
+    out["scan"] = [jax.tree.map(lambda x: x[:n], g) for g in params["scan"]]
+    return out
+
+
+def calibrate(args, cfg, params):
+    """PTQ on a few synthetic prompts through the unrolled view of the
+    served params (per-layer site names), then layer-shared quant params
+    for the scan layout (DESIGN.md §4). Returns (policy, act_state)."""
+    import dataclasses
+    from repro.core import peg_policy
+    pol = peg_policy(4)
+    if args.weight_bits == 4:
+        # sub-8-bit weights (paper Tables 5-7): symmetric int4 grid,
+        # MSE-fit ranges; activations stay on the W8A8/PEG policy
+        from repro.core import QuantizerConfig, RangeEstimator
+        pol = dataclasses.replace(
+            pol, weight_default=QuantizerConfig(
+                bits=4, symmetric=True, estimator=RangeEstimator.MSE))
+    calib = [{"tokens": jax.random.randint(
+        jax.random.PRNGKey(10 + i), (2, args.prompt_len), 0,
+        cfg.vocab_size)} for i in range(2)]
+
+    def fwd(p, b, ctx):
+        logits, _ = tfm.forward(cfg, p, b["tokens"], ctx=ctx)
+        return logits
+    qm = ptq(fwd, tfm.unrolled_view(cfg, params), calib, pol,
+             collect_inputs=args.deploy_int8)
+    # collapse per-layer sites to shared "layer/..." names (the first
+    # layer's grid)
+    shared = {}
+    for site, qp in qm.act_state.items():
+        base = "layer/" + site.split("/", 1)[1] if site.startswith("layer") \
+            else site
+        shared.setdefault(base, qp)
+    return pol, shared
+
+
+def deploy_errors(args, cfg, ref_fp, params, pol, state, ctx_factory):
+    """``rel_errors`` of the integer path against its references on the
+    first REF_SUPERS super-blocks, whose float weights ``ref_fp`` are all
+    that is kept: ``"int8"``, the int8 kernels (``ctx_factory``) vs the
+    fake-quant forward; at ``--kv-bits`` 8 or 4 ``"kv"``, the quantized vs
+    the float KV cache over prefill + 4 teacher-forced decode steps. Both
+    sides run f32 at ``highest`` precision: this checks the kernels
+    against the fake-quant arithmetic, not bf16 roundings of it."""
+    n_ref = min(REF_SUPERS, cfg.n_super)
+    ref_cfg = cfg.with_supers(n_ref)
+    int_params = _f32(first_supers(params, n_ref))
+    toks = jax.random.randint(jax.random.PRNGKey(99),
+                              (2, args.prompt_len), 0, cfg.vocab_size)
+    ref_fwd, _, _ = _ref_steps(ref_cfg, lambda: QuantCtx(
+        policy=pol, mode=Mode.APPLY, act_state=state))
+    int_fwd, prefill, decode = _ref_steps(ref_cfg, ctx_factory)
+    with jax.default_matmul_precision("highest"):
+        errors = {"int8": rel_errors(ref_fwd(_f32(ref_fp), toks),
+                                     int_fwd(int_params, toks))}
+    if args.kv_bits in (4, 8):
+        pairs = _teacher_forced(args, ref_cfg, prefill, decode, int_params,
+                                toks, 16, args.kv_bits)
+        errors["kv"] = rel_errors(np.stack([a for a, _ in pairs]),
+                                  np.stack([b for _, b in pairs]))
+    return errors
+
+
+def _teacher_forced(args, cfg, prefill, decode, params, toks, kv_a, kv_b,
+                    steps=4):
+    """Prefill + ``steps`` decode steps on f32 caches of ``kv_a`` and
+    ``kv_b`` bits, both fed the ``kv_a`` path's argmax; the per-step logits
+    of each."""
+    B = toks.shape[0]
+    ca = tfm.init_cache(cfg, B, args.max_len, dtype=jnp.float32,
+                        kv_bits=kv_a)
+    cb = tfm.init_cache(cfg, B, args.max_len, dtype=jnp.float32,
+                        kv_bits=kv_b)
+    with jax.default_matmul_precision("highest"):
+        la, ca = prefill(params, toks, ca)
+        lb, cb = prefill(params, toks, cb)
+        out = [(la, lb)]
+        pos = jnp.full((B, 1), toks.shape[1], jnp.int32)
+        for _ in range(steps):
+            cur = jnp.argmax(la, axis=-1).astype(jnp.int32)
+            la, ca = decode(params, cur, pos, ca)
+            lb, cb = decode(params, cur, pos, cb)
+            out.append((la, lb))
+            pos = pos + 1
+    return [(np.asarray(a), np.asarray(b)) for a, b in out]
+
+
+def _check_deploy(args, cfg, params, quant, ctx_factory, dtype):
+    """The integer path's gates: every deployable linear packed, the
+    quantized KV cache engaged, int8 == fake-quant reference and quantized
+    == float cache within tolerance (``deploy_errors``)."""
+    from repro.core import deploy
+    pol, state, deploy_acts, ref_fp = quant
+    n_packed, n_total = deploy.count_packed(params)
+    print(f"[deploy-int8] {n_packed}/{n_total} per-layer linears packed")
+    if n_packed != n_total:
+        raise SystemExit(f"[deploy-int8] FAIL: {n_total - n_packed} "
+                         f"linears left on the simulate path")
+    if args.kv_bits < 16:
+        caches = jax.eval_shape(lambda: tfm.init_cache(
+            cfg, 1, args.max_len, dtype=dtype, kv_bits=args.kv_bits,
+            paged=args.paged_kv, block_size=args.block_size))
+        nodes = tfm._cache_nodes(caches)
+        quant = [n for n in nodes if isinstance(n, (tfm.QuantKVCache,
+                                                    tfm.PagedQuantKVCache))]
+        grids = sum(1 for k in deploy_acts if k.endswith("/attn/kv"))
+        print(f"[kv-int{args.kv_bits}] quantized cache engaged on "
+              f"{len(quant)}/{len(nodes)} cache groups ({grids} calibrated "
+              f"k/v grids)")
+        if len(quant) != len(nodes):
+            raise SystemExit(f"[kv-int{args.kv_bits}] FAIL: cache not "
+                             f"quantized")
+
+    n_layers = cfg.with_supers(min(REF_SUPERS, cfg.n_super)).num_layers
+    errors = deploy_errors(args, cfg, ref_fp, params, pol, state,
+                           ctx_factory)
+    gate("deploy-int8", f"int8 vs fake-quant, first {n_layers} layers",
+         errors["int8"], TOL_F32 if dtype == jnp.float32 else TOL_INT8)
+    if "kv" in errors:
+        what = (f"int{args.kv_bits} vs float KV cache over prefill + 4 "
+                f"decode steps, first {n_layers} layers")
+        if args.kv_bits == 8:
+            gate("kv-int8", what, errors["kv"], TOL_KV8)
+        else:
+            # int4 is lossy by construction: quantified, not asserted
+            print(f"[kv-int4] {what}: rel rms logits error "
+                  f"{errors['kv'][0]:.4%}, max rel {errors['kv'][1]:.4%}")
+
+    if args.kv_bits == 4:
+        # drift quantification (int4 vs int8 cache): max-abs logit delta
+        # and greedy-token match rate, teacher-forced on the int8 path's
+        # argmax so both see identical inputs
+        n_ref = min(REF_SUPERS, cfg.n_super)
+        ref_cfg = cfg.with_supers(n_ref)
+        _, prefill, decode = _ref_steps(ref_cfg, ctx_factory)
+        toks = jax.random.randint(jax.random.PRNGKey(99),
+                                  (2, args.prompt_len), 0, cfg.vocab_size)
+        pairs = _teacher_forced(args, ref_cfg, prefill, decode,
+                                _f32(first_supers(params, n_ref)), toks, 8, 4)
+        delta = max(float(jnp.max(jnp.abs(a - b))) for a, b in pairs)
+        matched = sum(int(jnp.sum(jnp.argmax(a, axis=-1) ==
+                                  jnp.argmax(b, axis=-1))) for a, b in pairs)
+        total = sum(a.shape[0] for a, _ in pairs)
+        print(f"[kv-int4] int4 vs int8 cache drift over prefill + "
+              f"{len(pairs) - 1} decode steps: max |logit delta| "
+              f"{delta:.5f}, greedy-token match {matched}/{total} "
+              f"({matched / total:.1%})")
+
+
+def _ref_steps(cfg, ctx_factory):
+    """Jitted un-cached forward (logits), prefill and decode step of
+    ``cfg`` under ``ctx_factory``'s quantization. Jitted, not eager: the
+    range search of a linear left unpacked (the head, 32000 x 3840 on an
+    MSE grid of 100 points) then fuses into its reduction instead of
+    materializing a (grid, K, N) f32 temporary."""
+    fwd = jax.jit(lambda p, t: tfm.forward(cfg, p, t, ctx=ctx_factory())[0])
+    prefill = jax.jit(lambda p, t, c: tfm.prefill(cfg, p, t, c,
+                                                  ctx=ctx_factory()))
+    decode = jax.jit(lambda p, t, pos, c: tfm.decode_step(
+        cfg, p, t, pos, c, ctx=ctx_factory()))
+    return fwd, prefill, decode
+
+
+def replay(args, cfg, params, prompts, teacher, n_live, *, dtype, admit,
+           chunk_step, decode):
+    """Cached logits of the served program: ``prompts`` (B, T) through the
+    serving steps' prefill (chunked as served) on a fresh cache, then
+    decode steps fed ``teacher`` (B, N-1). Only the first ``n_live`` lanes
+    hold requests. Returns (n_live, N, V)."""
+    B, T = prompts.shape
+    live = np.arange(B) < n_live
+    # a fully mapped identity table when paged, as the static scheduler's
+    cache = tfm.init_cache(cfg, B, args.max_len, dtype=dtype,
+                           kv_bits=args.kv_bits, paged=args.paged_kv,
+                           block_size=args.block_size)
+    if args.prefill_chunk:
+        C = args.prefill_chunk
+        for off in range(0, T, C):
+            c = min(C, T - off)
+            toks = np.zeros((B, C), np.int32)
+            posm = np.full((B, C), -1, np.int32)
+            toks[live, C - c:] = prompts[live, off:off + c]
+            posm[live, C - c:] = np.arange(off, off + c, dtype=np.int32)
+            last, cache = chunk_step(params, toks, posm, live & (off == 0),
+                                     cache)
+    else:
+        posm = np.where(live[:, None], np.arange(T, dtype=np.int32), -1)
+        last, cache = admit(params, prompts, posm, live, cache)
+    cached = [last]
+    for i in range(teacher.shape[1]):
+        pos = np.where(live, T + i, -1).astype(np.int32)[:, None]
+        last, cache = decode(params, teacher[:, i:i + 1], pos, cache)
+        cached.append(last)
+    return np.asarray(jnp.concatenate(cached, axis=1)[:n_live])
+
+
+def uncached(cfg, params, seqs, T, *, ctx_factory, dist=None):
+    """Reference logits at positions T-1.. of ``seqs``: one un-cached
+    forward at ``highest`` matmul precision."""
+    def fwd(p, s):
+        ctx = ctx_factory() if ctx_factory is not None else None
+        return tfm.forward(cfg, p, s, ctx=ctx, dist=dist)[0][:, T - 1:]
+    with jax.default_matmul_precision("highest"):
+        return np.asarray(jax.jit(fwd)(params, seqs))
+
+
+def _verify(args, cfg, params, requests, *, dist, ctx_factory, dtype, admit,
+            chunk_step, decode):
+    """Check what was served. Every request got its tokens. The served
+    requests' prompts, continued by N-1 tokens drawn from ``--seed``, are
+    replayed through the serving steps, with the served params, dtype and
+    matmul precision, on a fresh cache: these cached prefill + decode
+    logits must match an un-cached forward over the same sequence (gated).
+    Greedy agreement of the served tokens with the un-cached forward over
+    prompt + served tokens is printed. Returns the replayed logits (a
+    function of the seed and the model only, so runs compare)."""
+    short = [(r.rid, len(r.tokens_out)) for r in requests
+             if len(r.tokens_out) != r.max_new_tokens]
+    if short:
+        raise SystemExit(f"[verify] FAIL: requests (rid, tokens) {short} "
+                         f"did not get their tokens")
+    print(f"[verify] all {len(requests)} requests got their "
+          f"{sorted({r.max_new_tokens for r in requests})} tokens")
+    B = args.batch_slots
+    reqs = requests[:B]
+    n_live = len(reqs)
+    T = args.prompt_len
+    N = min(len(r.tokens_out) for r in reqs)
+    prompts = np.zeros((B, T), np.int32)
+    served = np.zeros((B, N), np.int32)
+    for i, r in enumerate(reqs):
+        prompts[i] = r.prompt
+        served[i] = r.tokens_out[:N]
+    teacher = np.random.RandomState(args.seed + 1).randint(
+        10, cfg.vocab_size, size=(B, N)).astype(np.int32)[:, :N - 1]
+
+    cached = replay(args, cfg, params, prompts, teacher, n_live, dtype=dtype,
+                    admit=admit, chunk_step=chunk_step, decode=decode)
+    ref = uncached(cfg, params, np.concatenate([prompts, teacher],
+                                               axis=1)[:n_live], T,
+                   ctx_factory=ctx_factory, dist=dist)
+    ref_served = uncached(cfg, params, np.concatenate(
+        [prompts, served[:, :N - 1]], axis=1)[:n_live], T,
+        ctx_factory=ctx_factory, dist=dist)
+    agree = float(np.mean(np.argmax(ref_served, axis=-1) == served[:n_live]))
+    print(f"[verify] greedy agreement of the {n_live}x{N} served tokens "
+          f"with the un-cached forward: {agree:.1%} (printed, not gated: "
+          f"argmax can flip on rounding)")
+    what = (f"cached prefill + {N - 1} decode steps vs un-cached forward, "
+            f"{cfg.num_layers} layers")
+    errors = rel_errors(ref, cached)
+    if args.kv_bits == 4:
+        # the int4 cache is lossy by construction: quantified, not gated
+        print(f"[verify] {what}: rel rms logits error {errors[0]:.3e}, max "
+              f"rel {errors[1]:.3e} (int4 cache: reported, not gated)")
+    elif dtype == jnp.float32:
+        gate("verify", what, errors, TOL_F32)
+    else:
+        gate("verify", what, errors,
+             TOL_BF16 if ctx_factory is None else TOL_SERVED)
+    return cached
+
+
+def deploy_ctx_factory(pol, state, deploy_acts):
+    """QuantCtx factory of the integer path (``Mode.DEPLOY``)."""
+    def ctx_factory():
+        return QuantCtx(policy=pol, mode=Mode.DEPLOY, act_state=state,
+                        deploy_acts=deploy_acts)
+    return ctx_factory
+
+
+def build_model(args, cfg, dtype, dist):
+    """The served model: random params of ``cfg`` from ``--seed``
+    (stacked, placed for ``dist``), calibrated with ``--quantize`` and
+    packed with ``--deploy-int8``. Returns ``(params, ctx_factory,
+    quant)``; ``quant`` is ``(pol, act_state, deploy_acts, ref_fp)`` on the
+    integer path (``ref_fp``: the float weights of the first REF_SUPERS
+    super-blocks, the rest are dropped once packed), else None."""
+    key = jax.random.PRNGKey(args.seed)
+    init = functools.partial(tfm.init_params, cfg, stacked=True, dtype=dtype)
+    shardings = (make_param_shardings(jax.eval_shape(init, key), dist)
+                 if dist is not None else None)
+    # jitted: the stacks are generated in place, without eager temporaries
+    params = jax.jit(init, out_shardings=shardings)(key)
+    if not args.quantize:
+        return params, None, None
+    pol, state = calibrate(args, cfg, params)
+    if not args.deploy_int8:
+        return params, (lambda: QuantCtx(policy=pol, mode=Mode.APPLY,
+                                         act_state=state)), None
+    from repro.core import build_deploy
+    ref_fp = first_supers(params, min(REF_SUPERS, cfg.n_super))
+    # rebinding drops the float weights: only ref_fp's stay
+    params, deploy_acts = build_deploy(cfg, params, pol, state)
+    return (params, deploy_ctx_factory(pol, state, deploy_acts),
+            (pol, state, deploy_acts, ref_fp))
+
+
+def serving_steps(cfg, dist, ctx_factory):
+    """The jitted serving steps: (prefill, admit, decode, chunk_step), the
+    cache argument donated where the scheduler replaces it."""
+    prefill = jax.jit(make_prefill_step(cfg, dist=dist,
+                                        ctx_factory=ctx_factory))
+    admit = jax.jit(make_admit_step(cfg, dist=dist,
+                                    ctx_factory=ctx_factory),
+                    donate_argnums=(4,))
+    decode = jax.jit(make_decode_step(cfg, dist=dist,
+                                      ctx_factory=ctx_factory),
+                     donate_argnums=(3,))
+    chunk_step = jax.jit(make_chunk_prefill_step(cfg, dist=dist,
+                                                 ctx_factory=ctx_factory),
+                         donate_argnums=(4,))
+    return prefill, admit, decode, chunk_step
 
 
 def main(argv=None):
@@ -278,30 +682,26 @@ def main(argv=None):
                  "telemetry/--stats-json flags")
     if args.tp < 1:
         ap.error("--tp must be >= 1")
-    if args.tp > 1 and not args.reduced:
-        ap.error("--tp is the host-simulated tensor-parallel mode "
-                 "(--reduced); the full-size path builds its own "
-                 "production mesh")
+    if args.verify and args.async_serve:
+        ap.error("--verify replays the synchronous serving steps "
+                 "(incompatible with --async)")
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     dist = None
+    dtype = jnp.bfloat16
     if args.reduced:
         cfg = cfg.reduced()
         dtype = jnp.float32
-        if args.tp > 1:
-            ndev = len(jax.devices())
-            if ndev < args.tp:
-                ap.error(
-                    f"--tp {args.tp}: only {ndev} device(s) visible; "
-                    "simulate CPU devices with XLA_FLAGS="
-                    f"--xla_force_host_platform_device_count={args.tp} "
-                    "(set BEFORE the process imports jax)")
-            mesh = jax.make_mesh((1, args.tp), ("data", "model"))
-            dist = make_dist(mesh)
-    else:
-        mesh = make_production_mesh(multi_pod=args.multi_pod)
-        dist = make_dist(mesh)
-        dtype = jnp.bfloat16
+    if args.tp > 1 or not args.reduced:
+        ndev = len(jax.local_devices())
+        if ndev < args.tp:
+            ap.error(
+                f"--tp {args.tp}: only {ndev} device(s) visible; "
+                "simulate CPU devices with XLA_FLAGS="
+                f"--xla_force_host_platform_device_count={args.tp} "
+                "(set BEFORE the process imports jax)")
+        dist = make_dist(make_serving_mesh(args.tp))
 
     # per-lane table width: ring-window bounded for all-window archs
     # (ceil(S_w / block_size) instead of ceil(max_len / block_size))
@@ -330,144 +730,15 @@ def main(argv=None):
     except ValueError as e:
         ap.error(f"--max-len / --num-blocks too small: {e}")
 
-    key = jax.random.PRNGKey(args.seed)
-    params = tfm.init_params(cfg, key, stacked=True, dtype=dtype)
-    if dist is not None:
-        params = jax.tree.map(jax.device_put, params,
-                              make_param_shardings(params, dist))
-
-    ctx_factory = None
-    if args.quantize:
-        # calibrate on a few synthetic prompts using the unrolled layout,
-        # then serve with layer-shared quant params (DESIGN.md §4)
-        from repro.core import peg_policy
-        import dataclasses
-        pol = peg_policy(4)
-        if args.weight_bits == 4:
-            # sub-8-bit weights (paper Tables 5-7): symmetric int4 grid,
-            # MSE-fit ranges; activations stay on the W8A8/PEG policy
-            from repro.core import QuantizerConfig, RangeEstimator
-            pol = dataclasses.replace(
-                pol, weight_default=QuantizerConfig(
-                    bits=4, symmetric=True,
-                    estimator=RangeEstimator.MSE))
-        flat_params = tfm.init_params(cfg, key, stacked=False, dtype=dtype)
-        calib = [{"tokens": jax.random.randint(
-            jax.random.PRNGKey(10 + i), (2, args.prompt_len), 0,
-            cfg.vocab_size)} for i in range(2)]
-
-        def fwd(p, b, ctx):
-            logits, _ = tfm.forward(cfg, p, b["tokens"], ctx=ctx)
-            return logits
-        qm = ptq(fwd, flat_params, calib, pol,
-                 collect_inputs=args.deploy_int8)
-        # collapse per-layer sites to shared "layer/..." names (median scale)
-        shared = {}
-        for site, qp in qm.act_state.items():
-            base = "layer/" + site.split("/", 1)[1] if site.startswith("layer") \
-                else site
-            shared.setdefault(base, qp)
-        state = dict(shared)
-
-        if args.deploy_int8:
-            from repro.core import build_deploy
-            fp_params = params
-            params, deploy_acts = build_deploy(cfg, params, pol, state)
-
-            def ctx_factory():
-                return QuantCtx(policy=pol, mode=Mode.DEPLOY,
-                                act_state=state, deploy_acts=deploy_acts)
-
-            # parity: integer path vs the fake-quant reference it replaces
-            toks = jax.random.randint(jax.random.PRNGKey(99),
-                                      (2, args.prompt_len), 0, cfg.vocab_size)
-            ref_ctx = QuantCtx(policy=pol, mode=Mode.APPLY, act_state=state)
-            logits_ref, _ = tfm.forward(cfg, fp_params, toks, ctx=ref_ctx)
-            logits_int, _ = tfm.forward(cfg, params, toks, ctx=ctx_factory())
-            diff = float(jnp.max(jnp.abs(logits_ref - logits_int)))
-            scale = float(jnp.max(jnp.abs(logits_ref)) + 1e-9)
-            print(f"[deploy-int8] max |fake-quant - int8| logits diff "
-                  f"{diff:.5f} (rel {diff / scale:.4%})")
-
-            if args.kv_bits in (4, 8):
-                # multi-step decode parity: quantized KV cache (fused
-                # decode kernel) vs the bf16/f32-cache integer path it
-                # replaces, teacher-forced on the bf16 path's argmax
-                B, steps = 2, 4
-                c16 = tfm.init_cache(cfg, B, args.max_len, dtype=dtype)
-                cq = tfm.init_cache(cfg, B, args.max_len, dtype=dtype,
-                                    kv_bits=args.kv_bits)
-                l16, c16 = tfm.prefill(cfg, params, toks, c16,
-                                       ctx=ctx_factory())
-                lq, cq = tfm.prefill(cfg, params, toks, cq,
-                                     ctx=ctx_factory())
-                worst = float(jnp.max(jnp.abs(l16 - lq)) /
-                              (jnp.max(jnp.abs(l16)) + 1e-9))
-                cur = jnp.argmax(l16, axis=-1).astype(jnp.int32)
-                pos = jnp.full((B, 1), toks.shape[1], jnp.int32)
-                for _ in range(steps):
-                    l16, c16 = tfm.decode_step(cfg, params, cur, pos, c16,
-                                               ctx=ctx_factory())
-                    lq, cq = tfm.decode_step(cfg, params, cur, pos, cq,
-                                             ctx=ctx_factory())
-                    rel = float(jnp.max(jnp.abs(l16 - lq)) /
-                                (jnp.max(jnp.abs(l16)) + 1e-9))
-                    worst = max(worst, rel)
-                    cur = jnp.argmax(l16, axis=-1).astype(jnp.int32)
-                    pos = pos + 1
-                print(f"[kv-int{args.kv_bits}] max rel logits diff over "
-                      f"prefill + {steps} decode steps vs bf16 cache: "
-                      f"{worst:.4%}")
-
-            if args.kv_bits == 4:
-                # drift quantification (int4 vs int8 cache): max-abs
-                # logit delta and greedy-token match rate, teacher-forced
-                # on the int8 path's argmax so both see identical inputs
-                B, steps = 2, 4
-                c8 = tfm.init_cache(cfg, B, args.max_len, dtype=dtype,
-                                    kv_bits=8)
-                c4 = tfm.init_cache(cfg, B, args.max_len, dtype=dtype,
-                                    kv_bits=4)
-                l8, c8 = tfm.prefill(cfg, params, toks, c8,
-                                     ctx=ctx_factory())
-                l4, c4 = tfm.prefill(cfg, params, toks, c4,
-                                     ctx=ctx_factory())
-                delta = float(jnp.max(jnp.abs(l8 - l4)))
-                matched = int(jnp.sum(jnp.argmax(l4, axis=-1) ==
-                                      jnp.argmax(l8, axis=-1)))
-                total = B
-                cur = jnp.argmax(l8, axis=-1).astype(jnp.int32)
-                pos = jnp.full((B, 1), toks.shape[1], jnp.int32)
-                for _ in range(steps):
-                    l8, c8 = tfm.decode_step(cfg, params, cur, pos, c8,
-                                             ctx=ctx_factory())
-                    l4, c4 = tfm.decode_step(cfg, params, cur, pos, c4,
-                                             ctx=ctx_factory())
-                    delta = max(delta, float(jnp.max(jnp.abs(l8 - l4))))
-                    matched += int(jnp.sum(jnp.argmax(l4, axis=-1) ==
-                                           jnp.argmax(l8, axis=-1)))
-                    total += B
-                    cur = jnp.argmax(l8, axis=-1).astype(jnp.int32)
-                    pos = pos + 1
-                print(f"[kv-int4] int4 vs int8 cache drift over prefill + "
-                      f"{steps} decode steps: max |logit delta| "
-                      f"{delta:.5f}, greedy-token match {matched}/{total} "
-                      f"({matched / total:.1%})")
-        else:
-            def ctx_factory():
-                return QuantCtx(policy=pol, mode=Mode.APPLY, act_state=state)
-
-    prefill = jax.jit(make_prefill_step(cfg, dist=dist,
-                                        ctx_factory=ctx_factory))
-    admit = jax.jit(make_admit_step(cfg, dist=dist,
-                                    ctx_factory=ctx_factory),
-                    donate_argnums=(4,))
-    decode = jax.jit(make_decode_step(cfg, dist=dist,
-                                      ctx_factory=ctx_factory),
-                     donate_argnums=(3,))
-    chunk_step = jax.jit(make_chunk_prefill_step(cfg, dist=dist,
-                                                 ctx_factory=ctx_factory),
-                         donate_argnums=(4,))
+    params, ctx_factory, quant = build_model(args, cfg, dtype, dist)
+    print(f"[serve] {cfg.name}: {cfg.num_layers} layers, d_model "
+          f"{cfg.d_model}, {cfg.num_heads}/{cfg.num_kv_heads} heads of "
+          f"{cfg.hd}, d_ff {cfg.d_ff}, {dtype.__name__} on "
+          f"{jax.devices()[0].platform} x{args.tp}")
+    if args.deploy_int8:
+        _check_deploy(args, cfg, params, quant, ctx_factory, dtype)
+    del quant
+    prefill, admit, decode, chunk_step = serving_steps(cfg, dist, ctx_factory)
 
     telemetry = None
     if args.trace or args.metrics_every or args.quant_telemetry:
@@ -623,6 +894,8 @@ def main(argv=None):
                       f"emit identical greedy tokens for all "
                       f"{len(requests)} requests")
         return None
+    if args.warmup:
+        run(args.scheduler, make_requests(), chunk=args.prefill_chunk)
     stats = run(args.scheduler, requests, chunk=args.prefill_chunk,
                 tel=telemetry)
     if args.paged_kv and args.scheduler == "continuous":
@@ -698,6 +971,10 @@ def main(argv=None):
                 print(f"[quant-health] {name}: n={st['n']} "
                       f"min {st['min']:.3e} p50 {st['p50']:.3e} "
                       f"p99 {st['p99']:.3e} max {st['max']:.3e}")
+    if args.verify:
+        stats.replayed_logits = _verify(
+            args, cfg, params, requests, dist=dist, ctx_factory=ctx_factory,
+            dtype=dtype, admit=admit, chunk_step=chunk_step, decode=decode)
     if args.stats_json:
         import json
         with open(args.stats_json, "w") as f:

@@ -656,8 +656,35 @@ def _kv_zero_points(kvq, B, KV):
             jnp.broadcast_to(jnp.asarray(kvq.v_zp, jnp.float32), (B, KV)))
 
 
+def _kv_head_parallel(dist, kernel, operands):
+    """``kernel(*arrays)`` for a decode-attention kernel returning
+    (B, KV, G, hd). ``operands``: [(array | None, its kv-head axis | None)].
+
+    XLA cannot partition a Mosaic kernel, so over a tensor-parallel mesh
+    (``dist``) the kernel runs inside a ``shard_map``: each device attends
+    with its own kv heads (and their query groups), the rest of every
+    operand replicated. Kv heads that do not split evenly over the mesh
+    run replicated on every device."""
+    arrays = [a for a, _ in operands]
+    if dist is None or dist.tp_size == 1:
+        return kernel(*arrays)
+    from jax.sharding import PartitionSpec as P
+    tp = dist.tp_axis
+    kv = arrays[0].shape[1]
+    split = kv % dist.tp_size == 0
+
+    def spec(a, axis):
+        if a is None or axis is None or not split:
+            return P()
+        return P(*[tp if i == axis else None for i in range(a.ndim)])
+    return jax.shard_map(
+        kernel, mesh=dist.mesh,
+        in_specs=tuple(spec(a, axis) for a, axis in operands),
+        out_specs=P(None, tp) if split else P(), check_vma=False)(*arrays)
+
+
 def _quant_decode_attend(q, cache: QuantKVCache, q_pos, cfg: AttnConfig,
-                         ctx, prefix, kvq=None):
+                         ctx, prefix, kvq=None, dist=None):
     """Decode step through the fused int8 attention kernel.
 
     q: (B, 1, H, hd) (already RoPE'd / site-quantized); queries enter on
@@ -680,18 +707,26 @@ def _quant_decode_attend(q, cache: QuantKVCache, q_pos, cfg: AttnConfig,
     qg = q.reshape(B, KV, G, hd).astype(jnp.float32)
     q_q, qs, qz = _quantize_decode_q(qg, q_site)
     kz, vz = _kv_zero_points(kvq, B, KV)
-    out = kops.int8_attend_decode(
-        q_q, qs * cfg.scale, cache.k_q, cache.k_s, cache.v_q, cache.v_s,
-        cache.pos, q_pos[:, 0], q_zp=qz, k_zp=kz, v_zp=vz,
-        window=cfg.window,
-        logit_softcap=cfg.logit_softcap,
-        kv_bits=4 if isinstance(cache, Quant4KVCache) else 8, **sm_kwargs)
+    sm, smo = sm_kwargs.pop("sm_quant"), sm_kwargs.pop("smo_quant")
+
+    def kernel(q_q, qs, k_q, k_s, v_q, v_s, k_pos, q_pos, qz, kz, vz, sm,
+               smo):
+        return kops.int8_attend_decode(
+            q_q, qs, k_q, k_s, v_q, v_s, k_pos, q_pos, q_zp=qz, k_zp=kz,
+            v_zp=vz, window=cfg.window, logit_softcap=cfg.logit_softcap,
+            kv_bits=4 if isinstance(cache, Quant4KVCache) else 8,
+            sm_quant=sm, smo_quant=smo, **sm_kwargs)
+    out = _kv_head_parallel(dist, kernel, [
+        (q_q, 1), (qs * cfg.scale, 1), (cache.k_q, 2), (cache.k_s, 2),
+        (cache.v_q, 2), (cache.v_s, 2), (cache.pos, None),
+        (q_pos[:, 0], None), (qz, 1), (kz, 1), (vz, 1), (sm, None),
+        (smo, None)])
     return out.reshape(B, 1, H, hd).astype(q.dtype)
 
 
 def _paged_quant_decode_attend(q, cache: PagedQuantKVCache, block_table,
                                q_pos, cfg: AttnConfig, ctx, prefix,
-                               kvq=None):
+                               kvq=None, dist=None):
     """Decode step through the paged int8 attention kernel — the
     :func:`_quant_decode_attend` twin over a block-paged arena (same site
     grids, zero-point corrections and fallback rule; block gather + the
@@ -709,19 +744,28 @@ def _paged_quant_decode_attend(q, cache: PagedQuantKVCache, block_table,
     qg = q.reshape(B, KV, G, hd).astype(jnp.float32)
     q_q, qs, qz = _quantize_decode_q(qg, q_site)
     kz, vz = _kv_zero_points(kvq, B, KV)
-    out = kops.paged_int8_attend_decode(
-        q_q, qs * cfg.scale, cache.k_q, cache.k_s, cache.v_q, cache.v_s,
-        block_table, q_pos[:, 0],
-        s_cap=paged_capacity(block_table, bs, cfg.window),
-        q_zp=qz, k_zp=kz, v_zp=vz, window=cfg.window,
-        logit_softcap=cfg.logit_softcap,
-        kv_bits=4 if isinstance(cache, PagedQuant4KVCache) else 8,
-        **sm_kwargs)
+    sm, smo = sm_kwargs.pop("sm_quant"), sm_kwargs.pop("smo_quant")
+
+    def kernel(q_q, qs, k_q, k_s, v_q, v_s, table, q_pos, qz, kz, vz, sm,
+               smo):
+        return kops.paged_int8_attend_decode(
+            q_q, qs, k_q, k_s, v_q, v_s, table, q_pos,
+            s_cap=paged_capacity(table, bs, cfg.window),
+            q_zp=qz, k_zp=kz, v_zp=vz, window=cfg.window,
+            logit_softcap=cfg.logit_softcap,
+            kv_bits=4 if isinstance(cache, PagedQuant4KVCache) else 8,
+            sm_quant=sm, smo_quant=smo, **sm_kwargs)
+    out = _kv_head_parallel(dist, kernel, [
+        (q_q, 1), (qs * cfg.scale, 1), (cache.k_q, 2), (cache.k_s, 2),
+        (cache.v_q, 2), (cache.v_s, 2), (block_table, None),
+        (q_pos[:, 0], None), (qz, 1), (kz, 1), (vz, 1), (sm, None),
+        (smo, None)])
     return out.reshape(B, 1, H, hd).astype(q.dtype)
 
 
 def _paged_decode_attend(q, cache: PagedKVCache, block_table, q_pos,
-                         cfg: AttnConfig, ctx, prefix):
+                         cfg: AttnConfig, ctx, prefix,
+                         dist=None):
     """Decode step through the paged bf16/f32 attention kernel. Applies
     the softmax_in/softmax_out sites in-kernel when they are per-tensor
     (matching _dense_attend's placement); returns None when a site is
@@ -738,10 +782,17 @@ def _paged_decode_attend(q, cache: PagedKVCache, block_table, q_pos,
     KV, G = cfg.num_kv_heads, cfg.q_groups
     bs = cache.pos.shape[1]
     qg = q.reshape(B, KV, G, hd).astype(jnp.float32) * cfg.scale
-    out = kops.paged_attend_decode(
-        qg, cache.k, cache.v, block_table, q_pos[:, 0],
-        s_cap=paged_capacity(block_table, bs, cfg.window),
-        window=cfg.window, logit_softcap=cfg.logit_softcap, **sm_kwargs)
+    sm, smo = sm_kwargs.pop("sm_quant"), sm_kwargs.pop("smo_quant")
+
+    def kernel(qg, k, v, table, q_pos, sm, smo):
+        return kops.paged_attend_decode(
+            qg, k, v, table, q_pos,
+            s_cap=paged_capacity(table, bs, cfg.window),
+            window=cfg.window, logit_softcap=cfg.logit_softcap,
+            sm_quant=sm, smo_quant=smo, **sm_kwargs)
+    out = _kv_head_parallel(dist, kernel, [
+        (qg, 1), (cache.k, 2), (cache.v, 2), (block_table, None),
+        (q_pos[:, 0], None), (sm, None), (smo, None)])
     return out.reshape(B, 1, H, hd).astype(q.dtype)
 
 
@@ -762,7 +813,7 @@ def _prev_positions(positions):
 def attention_block(p, x, positions, cfg: AttnConfig, *, ctx=None,
                     prefix="attn", cache: Optional[KVCache] = None,
                     chunked: Optional[bool] = None, block_table=None,
-                    append: bool = False
+                    append: bool = False, dist=None
                     ) -> Tuple[jnp.ndarray, Optional[KVCache]]:
     """x: (B, T, D). p: dict with wq (D,H*hd), wk/wv (D,KV*hd), wo (H*hd,D).
 
@@ -772,7 +823,8 @@ def attention_block(p, x, positions, cfg: AttnConfig, *, ctx=None,
     Paged caches (PagedKVCache / PagedQuantKVCache) additionally need
     ``block_table`` (B, max_blocks) int32 — writes scatter through it and
     decode runs the paged kernels (gather + derived-position mask
-    in-kernel).
+    in-kernel). Over a tensor-parallel mesh (``dist``) the decode kernels
+    run per kv-head shard (:func:`_kv_head_parallel`).
 
     ``append=True`` is the chunked-prefill contract: the T tokens are ONE
     chunk appended at each lane's current position, so queries attend over
@@ -881,10 +933,11 @@ def attention_block(p, x, positions, cfg: AttnConfig, *, ctx=None,
             if quantized:
                 out = _paged_quant_decode_attend(q, new_cache, block_table,
                                                  positions, cfg, ctx,
-                                                 prefix, kvq)
+                                                 prefix, kvq, dist=dist)
             else:
                 out = _paged_decode_attend(q, new_cache, block_table,
-                                           positions, cfg, ctx, prefix)
+                                           positions, cfg, ctx, prefix,
+                                           dist=dist)
             if out is None:
                 k_att, v_att = paged_gather_kv(new_cache, block_table,
                                                cfg.window, kvq)
@@ -896,7 +949,7 @@ def attention_block(p, x, positions, cfg: AttnConfig, *, ctx=None,
             new_cache = _write_kv(cache, k, v, positions, slots, bidx, kvq)
             if quantized:
                 out = _quant_decode_attend(q, new_cache, positions, cfg,
-                                           ctx, prefix, kvq)
+                                           ctx, prefix, kvq, dist=dist)
                 if out is None:       # kernel can't express: dequant + flash
                     k_att, v_att = dequantize_kv(new_cache, kvq)
                     kpos_att = new_cache.pos

@@ -163,8 +163,7 @@ def _moe_sharded(cfg: ModelConfig, p, x, dist: DistContext):
     else:
         w_specs = (P(None, fsdp, tp), P(None, fsdp, tp), P(None, tp, fsdp))
 
-    from repro.core.jax_compat import shard_map
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(fsdp, None),) + w_specs + (tok_spec,),
         out_specs=tok_spec,
@@ -237,10 +236,12 @@ def block_apply(cfg: ModelConfig, kind: str, p, x, positions, *, ctx=None,
         attn_out, new_cache = attention_block(
             p["attn"], h, positions, acfg, ctx=ctx, prefix=f"{prefix}/attn",
             cache=cache, chunked=chunked, block_table=block_table,
-            append=append)
+            append=append, dist=dist)
         if cfg.post_norm:
             attn_out = _norm(cfg, p["post_ln1"], attn_out)
-        x = x + attn_out
+        # the residual stream keeps its dtype (f32 on the integer path,
+        # whose kernels emit f32; see _embed)
+        x = x + attn_out.astype(x.dtype)
         if ctx is not None:
             x = ctx.act(f"{prefix}/residual_attn", x)
         h = _ffn_input(cfg, p, x, ctx, prefix)
@@ -250,7 +251,7 @@ def block_apply(cfg: ModelConfig, kind: str, p, x, positions, *, ctx=None,
             ffn_out = _norm(cfg, p["post_ln2"], ffn_out)
         if ctx is not None:
             ffn_out = ctx.act(f"{prefix}/ffn_out", ffn_out)
-        x = x + ffn_out
+        x = x + ffn_out.astype(x.dtype)
         if ctx is not None:
             x = ctx.act(f"{prefix}/residual_ffn", x)
         return x, new_cache
@@ -267,7 +268,7 @@ def block_apply(cfg: ModelConfig, kind: str, p, x, positions, *, ctx=None,
                              dist=dist)
         if ctx is not None:
             ffn_out = ctx.act(f"{prefix}/ffn_out", ffn_out)
-        x = x + ffn_out
+        x = x + ffn_out.astype(x.dtype)
         if ctx is not None:
             x = ctx.act(f"{prefix}/residual_ffn", x)
         return x, new_state
@@ -382,11 +383,14 @@ def init_params(cfg: ModelConfig, key, *, stacked: bool = True,
                                        dtype).T
 
     if stacked:
+        # vmap over the layer keys builds each stack directly (no per-layer
+        # list + stack, i.e. no second full copy); the values are those of
+        # the per-layer inits.
         scan_groups = []
         for j, kind in enumerate(cfg.block_pattern):
-            per = [init_block_params(cfg, kind, keys[s * n_pat + j], dtype)
-                   for s in range(n_super)]
-            scan_groups.append(jax.tree.map(lambda *xs: jnp.stack(xs), *per))
+            ks = jnp.stack([keys[s * n_pat + j] for s in range(n_super)])
+            scan_groups.append(jax.vmap(functools.partial(
+                init_block_params, cfg, kind, dtype=dtype))(ks))
         params["scan"] = scan_groups
         params["tail"] = [init_block_params(cfg, kind,
                                             keys[n_super * n_pat + i], dtype)
@@ -395,6 +399,35 @@ def init_params(cfg: ModelConfig, key, *, stacked: bool = True,
         params["layers"] = [init_block_params(cfg, kind, keys[i], dtype)
                             for i, kind in enumerate(plan)]
     return params
+
+
+class _LayerSlices:
+    """``params["layers"]`` of a stacked pytree: layer ``i`` is sliced out
+    of its scan stack when it is indexed, so a loop over the layers holds
+    one layer's copy at a time."""
+
+    def __init__(self, cfg: ModelConfig, params):
+        self._cfg, self._params = cfg, params
+
+    def __len__(self):
+        return self._cfg.num_layers
+
+    def __getitem__(self, i):
+        n_pat = len(self._cfg.block_pattern)
+        n_scan = self._cfg.n_super * n_pat
+        if i >= n_scan:
+            return self._params["tail"][i - n_scan]
+        return jax.tree.map(lambda x: x[i // n_pat],
+                            self._params["scan"][i % n_pat])
+
+
+def unrolled_view(cfg: ModelConfig, params):
+    """The unrolled layout (per-layer site names ``layer{i}/...``) over
+    stacked params without copying them: for calibrating the params that
+    are served instead of a second, unstacked set."""
+    view = {k: v for k, v in params.items() if k not in ("scan", "tail")}
+    view["layers"] = _LayerSlices(cfg, params)
+    return view
 
 
 def attn_write_spans(cfg: ModelConfig, max_len: int) -> List[int]:
@@ -779,6 +812,7 @@ def _constrain(x, dist: Optional[DistContext], spec):
 
 
 def _embed(cfg: ModelConfig, params, tokens, embeds, ctx, dist=None):
+    from repro.core.calibration import Mode
     from repro.models.common import resolve_weight
     table = resolve_weight(params["embed"])
     if dist is not None and dist.onehot_embed and tokens.size <= 4096:
@@ -800,6 +834,12 @@ def _embed(cfg: ModelConfig, params, tokens, embeds, ctx, dist=None):
         # modality frontend stub: precomputed patch/frame embeddings are
         # prepended to the token embeddings (assignment: frontend is a stub).
         x = jnp.concatenate([embeds.astype(x.dtype), x], axis=1)
+    if ctx is not None and ctx.mode == Mode.DEPLOY:
+        # the integer path keeps its residual stream in f32: the int8
+        # kernels emit f32, and bf16 cannot hold every value of an 8-bit
+        # grid (code x scale), so bf16 roundings between sites would move
+        # values across the next site's requantization ties
+        x = x.astype(jnp.float32)
     if ctx is not None:
         x = ctx.act("embed/sum", x)
     return x
@@ -837,7 +877,24 @@ def forward(cfg: ModelConfig, params, tokens, *, embeds=None, ctx=None,
     append: chunked-prefill mode — the tokens are one chunk appended at
     each lane's current cache position; attention reads the cache (earlier
     chunks) in addition to the fresh tokens (see models.attention).
+
+    Under ``Mode.DEPLOY`` the float matmuls between the int8 kernels
+    (attention scores and values, the head) run at ``highest`` precision:
+    at the default precision a TPU rounds their f32 operands to bf16, and
+    those roundings move values across the next 8-bit site's ties.
     """
+    from repro.core.calibration import Mode
+    kw = dict(embeds=embeds, ctx=ctx, dist=dist, cache=cache,
+              positions=positions, remat=remat, chunked=chunked,
+              append=append)
+    if ctx is not None and ctx.mode == Mode.DEPLOY:
+        with jax.default_matmul_precision("highest"):
+            return _forward(cfg, params, tokens, **kw)
+    return _forward(cfg, params, tokens, **kw)
+
+
+def _forward(cfg: ModelConfig, params, tokens, *, embeds, ctx, dist, cache,
+             positions, remat, chunked, append):
     B, T = tokens.shape
     x = _embed(cfg, params, tokens, embeds, ctx, dist=dist)
     T_full = x.shape[1]

@@ -24,16 +24,8 @@ from repro.models.transformer import DistContext
 
 def make_abstract_mesh(shape: Tuple[int, ...],
                        axes: Tuple[str, ...]) -> AbstractMesh:
-    """Device-free mesh for sharding-rule logic, across jax API revisions.
-
-    Old jax took ``AbstractMesh(shape, axis_names)``; current versions take
-    a single tuple of ``(name, size)`` pairs. Build the pairs form first and
-    fall back for older installs.
-    """
-    try:
-        return AbstractMesh(tuple(zip(axes, shape)))
-    except TypeError:
-        return AbstractMesh(tuple(shape), tuple(axes))
+    """Device-free mesh for sharding-rule logic."""
+    return AbstractMesh(tuple(shape), tuple(axes))
 
 
 def make_dist(mesh: Mesh) -> DistContext:

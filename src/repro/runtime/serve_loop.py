@@ -168,12 +168,20 @@ class ServeStats:
     # request produced a token)
     tier_latency: Dict[int, TierLatency] = \
         dataclasses.field(default_factory=dict)
+    # ``serve.py --verify``: the replayed cached logits (n, N, V), a
+    # function of the seed and the model only (not part of to_json)
+    replayed_logits: Optional[np.ndarray] = dataclasses.field(
+        default=None, repr=False)
 
     def to_json(self) -> Dict[str, Any]:
-        """JSON-serializable dict of every field (nested RequestLatency /
-        TierLatency dataclasses included) — the machine-readable form
-        behind ``serve.py --stats-json`` and the serving bench rows."""
-        return dataclasses.asdict(self)
+        """JSON-serializable dict of every field but ``replayed_logits``
+        (nested RequestLatency / TierLatency dataclasses included) — the
+        machine-readable form behind ``serve.py --stats-json`` and the
+        serving bench rows."""
+        out = dataclasses.asdict(
+            dataclasses.replace(self, replayed_logits=None))
+        del out["replayed_logits"]
+        return out
 
 
 def _tree_bytes(tree) -> int:

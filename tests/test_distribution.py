@@ -103,7 +103,8 @@ MULTI_DEV_SCRIPT = textwrap.dedent("""
     import numpy as np
     from jax.sharding import PartitionSpec as P, NamedSharding
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 
     # 1) compressed cross-pod all-reduce ~= plain mean
     from repro.core.grad_compression import (make_crosspod_allreduce,

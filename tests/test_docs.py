@@ -29,6 +29,7 @@ DOCS = ["README.md", "docs/serving.md", "docs/kernels.md",
 # flags mentioned in the docs that belong to other CLIs, not serve.py
 FOREIGN_FLAGS = {
     "--sections",       # benchmarks/run.py
+    "--four-chips",     # chip_smoke.py
     "--xla",            # --xla_force_host_platform_device_count: an
                         # XLA_FLAGS value (the --tp docs), not a CLI flag
 }

@@ -315,7 +315,8 @@ SHARDED_SCRIPT = textwrap.dedent("""
     cfg = get_config("gemma2-2b").reduced()
     key = jax.random.PRNGKey(0)
     params = tfm.init_params(cfg, key, stacked=True, dtype=jnp.float32)
-    mesh = jax.make_mesh((1, 2), ("data", "model"))
+    from repro.launch.mesh import make_serving_mesh
+    mesh = make_serving_mesh(2)
     dist = make_dist(mesh)
     SPEC = [(4, 2), (8, 6), (3, 1), (6, 4)]
 
