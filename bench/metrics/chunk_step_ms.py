@@ -1,0 +1,7 @@
+"""Mean chunked-prefill call (ms), host clock, ending when its outputs are
+ready."""
+
+
+def read(rec):
+    d = [c["t1"] - c["t0"] for c in rec.calls if c["kind"] == "chunk"]
+    return 1e3 * sum(d) / len(d) if d else None

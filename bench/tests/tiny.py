@@ -1,0 +1,56 @@
+"""A cell small enough for the CPU (Pallas kernels in interpret mode):
+the configurations' architecture at toy widths under a short closed-loop
+mix, with the metric entries of BENCHMARK.json."""
+import copy
+import json
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+
+MODEL = {"num_layers": 2, "d_model": 64, "num_heads": 4, "num_kv_heads": 2,
+         "head_dim": 16, "d_ff": 128, "vocab_size": 128, "window": None,
+         "rope_theta": 10000.0, "rms_norm_eps": 1e-6,
+         "tie_embeddings": False}
+
+PRECISION = {"w8a8": {"path": "w8a8", "peg_groups": 4, "kv_bits": 8},
+             "bf16": {"path": "bf16", "kv_bits": 16}}
+
+MIX = {"kind": "closed_loop", "clients": 4, "think_s": 0,
+       "prompt": {"dist": "lognormal", "median": 12, "sigma": 0.5,
+                  "min": 4, "max": 24},
+       "output": {"dist": "lognormal", "median": 4, "sigma": 0.5,
+                  "min": 2, "max": 8},
+       "shared_prefix": 0, "low_id": 0, "block": 8, "queue": 4096}
+
+PARAMS = {"lanes": 4, "prefill_chunk": 8, "block_size": 4, "max_len": 32,
+          "num_blocks": 32, "calibration_len": 16,
+          "check": {"served_tokens": 128, "max_requests": 16,
+                    "min_checked": 32}}
+
+# The check's limit at these widths, by precision path, set as the cells'
+# limits are (CPU, 4 s windows, up to 128 served tokens checked).
+# w8a8: the program's widest gap read 0.27-0.69 over 12 seeds and the int4
+# control's 1.49-2.57; seed 11 run 16 times under load: program at most
+# 0.96, control at least 1.87.
+# bf16: the program's widest gap read 0-0.036 over 16 seeds (1-16); the
+# control, the program's own W8A8 path (the w8a8 cell on the same seeds),
+# 0.33-1.34 over seeds 1-12. (The reference on int8 grids read 0-0.18,
+# no higher than the program: it separates nothing, as on the chip.)
+LIMITS = {"w8a8": 1.3, "bf16": 0.12}
+
+
+def cell(path: str = "w8a8", **overrides):
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    params = copy.deepcopy(PARAMS)
+    params["check"]["max_gap_sd"] = LIMITS[path]
+    c = {"workload": {"name": f"tiny-{path}.chat", "config": f"tiny-{path}",
+                      "traffic": "tiny", "chips": 1},
+         "conf": {"name": f"tiny-{path}", "arch": "internlm2-20b",
+                  "source": "test", "model": dict(MODEL),
+                  "precision": dict(PRECISION[path])},
+         "mix": copy.deepcopy(MIX), "params": params,
+         "end_to_end": bench["end_to_end"],
+         "per_layer": [m for m in bench["per_layer"]
+                       if m["source"] != "device_trace"]}
+    c.update(overrides)
+    return c
