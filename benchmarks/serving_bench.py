@@ -69,16 +69,6 @@ record per-block bytes, resident lanes per MiB, and the int4 rows
 quantify the drift vs int8 in-bench (greedy-token match rate — int4 is
 lossy by construction, so drift is reported, not asserted away).
 
-A seventh section benches TELEMETRY overhead: the identical section-one
-workload served untraced and with the full observability stack armed
-(lifecycle tracer + periodic metrics snapshots,
-``runtime/telemetry.py``). Tracing must be observational only — traced
-== untraced greedy parity is asserted in-bench, the trace's request
-spans are reconciled against ``ServeStats`` (every request retired),
-and the overhead is reported as a tokens/s ratio. Rows come from
-``ServeStats.to_json()``, the same machine-readable form
-``serve.py --stats-json`` writes.
-
 ``python -m benchmarks.serving_bench`` (or benchmarks/run.py --sections
 serving) also writes machine-readable ``BENCH_serving.json``.
 """
@@ -162,11 +152,6 @@ OC_DEPLOY_MAX_LEN = 32
 OC_DEPLOY_LOW = (8, 16)
 OC_DEPLOY_HIGH = (16, 4)
 OC_DEPLOY_BLOCKS = 4
-
-# telemetry section: section-one workload, untraced vs fully armed
-# tracer + metrics — the overhead claim must be measured on the same
-# jitted steps (tracing adds host-side bookkeeping only, no retrace)
-TEL_METRICS_EVERY = 8
 
 # int4-KV section: same deploy-path workload at kv-bits 8 and 4 — the
 # capacity claim is per-block bytes, the cost claim is greedy drift
@@ -252,7 +237,6 @@ def bench():
     rows += bench_prefix()
     rows += bench_overcommit()
     rows += bench_kv4_lanes()
-    rows += bench_telemetry()
     return rows
 
 
@@ -855,96 +839,13 @@ def bench_kv4_lanes():
     return rows
 
 
-def bench_telemetry():
-    """Traced vs untraced continuous serving on the section-one workload.
-    Telemetry must be observational only: traced == untraced greedy
-    parity and span/stats reconciliation are asserted in-bench, and the
-    overhead lands in the rows as a tokens/s ratio. Rows are built from
-    ``ServeStats.to_json()`` — the same machine-readable form behind
-    ``serve.py --stats-json``."""
-    import io
-
-    from repro.runtime import ServeTelemetry
-
-    cfg = get_config("gemma2-2b").reduced()
-    params = tfm.init_params(cfg, jax.random.PRNGKey(0), stacked=True,
-                             dtype=jnp.float32)
-    admit = jax.jit(make_admit_step(cfg), donate_argnums=(4,))
-    decode = jax.jit(make_decode_step(cfg), donate_argnums=(3,))
-    prefill = jax.jit(make_prefill_step(cfg))
-
-    def init(b):
-        return tfm.init_cache(cfg, b, MAX_LEN, dtype=jnp.float32)
-
-    def run(reqs, tel):
-        return serve(prefill, admit, decode, init, params, reqs,
-                     scheduler="continuous", batch_slots=BATCH_SLOTS,
-                     max_len=MAX_LEN, telemetry=tel)
-
-    warm = [Request(rid=0, prompt=np.ones(PROMPT_LEN, np.int32),
-                    max_new_tokens=2) for _ in range(BATCH_SLOTS)]
-    run(warm, None)
-
-    rows, outs, tel = [], {}, None
-    for traced in (False, True):
-        best = None
-        for _ in range(REPEATS):
-            reqs = _requests(cfg)
-            t = (ServeTelemetry.create(trace=True,
-                                       metrics_every=TEL_METRICS_EVERY,
-                                       metrics_sink=io.StringIO())
-                 if traced else None)
-            s = run(reqs, t)
-            if best is None or s.tokens_per_s > best[0].tokens_per_s:
-                best = (s, t, reqs)
-        stats, t, reqs = best
-        name = "traced" if traced else "untraced"
-        if traced:
-            tel = t
-        outs[name] = [r.tokens_out for r in reqs]
-        sj = stats.to_json()
-        rows.append({
-            "name": f"serve_telemetry_{name}",
-            "telemetry": traced,
-            "batch_slots": BATCH_SLOTS,
-            "requests": N_REQUESTS,
-            "quotas": [SHORT_QUOTA, LONG_QUOTA],
-            "tokens": sj["tokens_generated"],
-            "prefill_calls": sj["prefill_calls"],
-            "decode_steps": sj["decode_steps"],
-            "wall_s": round(sj["wall_s"], 3),
-            "tokens_per_s": round(sj["tokens_per_s"], 1),
-            "slot_utilization": round(sj["slot_utilization"], 3),
-        })
-    assert outs["untraced"] == outs["traced"], \
-        "telemetry must be observational: traced greedy parity violated"
-    # reconcile the winning trace against its ServeStats: every request
-    # enqueued, admitted, and retired, on the scheduler's step budget
-    spans = tel.tracer.request_spans()
-    assert len(spans) == N_REQUESTS
-    assert all(s["retired"] for s in spans.values()), \
-        "trace spans must show every request retired"
-    base, trow = rows[-2], rows[-1]
-    hists = tel.tracer.latency_histograms()
-    trow["trace_events"] = len(tel.tracer.events)
-    trow["metrics_every"] = TEL_METRICS_EVERY
-    trow["decode_batch_p50_ms"] = round(hists["decode_batch"]["p50"], 3)
-    trow["decode_batch_p99_ms"] = round(hists["decode_batch"]["p99"], 3)
-    trow["tokens_per_s_vs_untraced"] = round(
-        trow["tokens_per_s"] / max(base["tokens_per_s"], 1e-9), 3)
-    trow["overhead_pct"] = round(
-        (1 - trow["tokens_per_s_vs_untraced"]) * 100, 1)
-    return rows
-
-
 def report(rows) -> str:
     hdr = ("name,kv_bits,tokens,decode_steps,wall_s,tokens_per_s,"
            "slot_utilization,peak_cache_bytes,speedup_vs_static,"
            "cache_bytes_vs_dense,max_decode_gap_ms,"
            "stall_reduction_vs_monolithic,prefill_tokens_processed,"
            "blocks_allocated,preemptions,swapped_blocks,recomputed_tokens,"
-           "queue_wait_steps,tier1_first_token_p99,"
-           "tokens_per_s_vs_untraced")
+           "queue_wait_steps,tier1_first_token_p99")
     lines = [hdr]
     for r in rows:
         lines.append(
@@ -963,8 +864,7 @@ def report(rows) -> str:
             f"{r.get('swapped_blocks', '')},"
             f"{r.get('recomputed_tokens', '')},"
             f"{r.get('queue_wait_steps', '')},"
-            f"{r.get('tier1_first_token_p99', '')},"
-            f"{r.get('tokens_per_s_vs_untraced', '')}")
+            f"{r.get('tier1_first_token_p99', '')}")
     return "\n".join(lines)
 
 

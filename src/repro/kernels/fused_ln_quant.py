@@ -108,6 +108,7 @@ def _call(x, gamma, beta, scale, zp, *, kind, emit, qmin, qmax, eps,
         ],
         out_specs=pl.BlockSpec((bt, d), lambda i: (i, 0)),
         interpret=interpret,
+        name=f"{kind}_{'quantize' if emit else 'fake_quant'}",
     )(gamma.astype(jnp.float32).reshape(1, d),
       beta.astype(jnp.float32).reshape(1, d), scale, zp, x)
 

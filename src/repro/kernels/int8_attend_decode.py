@@ -347,5 +347,6 @@ def int8_attend_decode(q_q: jnp.ndarray, q_scale: jnp.ndarray,
                                lambda i, kk: (i, 0, 0, 0, 0)),
         scratch_shapes=decode_scratch(kv, parts, g, w),
         interpret=interpret,
+        name="int8_attend_decode",
     )(*operands)
     return merge_parts(out, hd)

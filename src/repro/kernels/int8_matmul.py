@@ -220,6 +220,7 @@ def int8_matmul(a_q: jnp.ndarray, w_q: jnp.ndarray, s_a, s_w, *,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         scratch_shapes=[_vmem_scratch((bm, bn), jnp.int32)],
         interpret=interpret,
+        name="int8_matmul",
     )(*operands)
 
 
@@ -363,4 +364,5 @@ def int8_matmul_peg(a_q: jnp.ndarray, w_q: jnp.ndarray,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         scratch_shapes=[_vmem_scratch((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="int8_matmul_peg",
     )(*operands)

@@ -125,6 +125,8 @@ def _paged_call(kernel_operands, in_specs, *, b, kv, g, hd, nb, bs, s_cap,
         out_shape=jax.ShapeDtypeStruct((b, kv, parts, g, w), jnp.float32),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="paged_int8_attend_decode" if quantized
+        else "paged_attend_decode",
     )(jnp.asarray(block_table, jnp.int32), jnp.asarray(q_pos, jnp.int32),
       *operands)
     return merge_parts(out, hd)

@@ -49,6 +49,7 @@ def _call(x, scales, zps, *, qmin, qmax, emit, out_dtype, block_t,
         in_specs=[SMEM, SMEM, pl.BlockSpec((bt, d), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((bt, d), lambda i: (i, 0)),
         interpret=interpret,
+        name="peg_quantize" if emit else "peg_fake_quant",
     )(scales.astype(jnp.float32), zps.astype(jnp.float32), x)
 
 

@@ -445,20 +445,21 @@ def _write_kv(cache, k_new, v_new, pw, slots, bidx, kvq):
     per-head clip ranges. The result is rebuilt as ``type(cache)`` so the
     bit-width-marker subclass survives the write.
     Out-of-bounds slots (dead cells, see _write_slots) are dropped."""
-    if isinstance(cache, QuantKVCache):
-        kq, ks, vq, vs = _quantize_kv_writes(cache, k_new, v_new, kvq)
-        return type(cache)(
-            k_q=cache.k_q.at[bidx, slots].set(kq, mode="drop"),
-            v_q=cache.v_q.at[bidx, slots].set(vq, mode="drop"),
-            k_s=cache.k_s.at[bidx, slots].set(ks, mode="drop"),
-            v_s=cache.v_s.at[bidx, slots].set(vs, mode="drop"),
+    with jax.named_scope("kv_write"):
+        if isinstance(cache, QuantKVCache):
+            kq, ks, vq, vs = _quantize_kv_writes(cache, k_new, v_new, kvq)
+            return type(cache)(
+                k_q=cache.k_q.at[bidx, slots].set(kq, mode="drop"),
+                v_q=cache.v_q.at[bidx, slots].set(vq, mode="drop"),
+                k_s=cache.k_s.at[bidx, slots].set(ks, mode="drop"),
+                v_s=cache.v_s.at[bidx, slots].set(vs, mode="drop"),
+                pos=cache.pos.at[bidx, slots].set(pw, mode="drop"))
+        return KVCache(
+            k=cache.k.at[bidx, slots].set(k_new.astype(cache.k.dtype),
+                                          mode="drop"),
+            v=cache.v.at[bidx, slots].set(v_new.astype(cache.v.dtype),
+                                          mode="drop"),
             pos=cache.pos.at[bidx, slots].set(pw, mode="drop"))
-    return KVCache(
-        k=cache.k.at[bidx, slots].set(k_new.astype(cache.k.dtype),
-                                      mode="drop"),
-        v=cache.v.at[bidx, slots].set(v_new.astype(cache.v.dtype),
-                                      mode="drop"),
-        pos=cache.pos.at[bidx, slots].set(pw, mode="drop"))
 
 
 def _write_paged_kv(cache, k_new, v_new, pw, block_table, window, kvq):
@@ -469,27 +470,28 @@ def _write_paged_kv(cache, k_new, v_new, pw, block_table, window, kvq):
     unmapped blocks route to ``num_blocks`` so the scatter DROPS them —
     the same lane-safety contract as the dense path. Quantized arenas
     quantize in place exactly like _write_kv."""
-    num_blocks, bs = cache.pos.shape
-    s_cap = paged_capacity(block_table, bs, window)
-    L = jnp.mod(jnp.maximum(pw, 0), s_cap)
-    phys = jnp.take_along_axis(block_table, L // bs, axis=1)      # (B, T)
-    dead = (pw < 0) | (phys < 0)
-    phys = jnp.where(dead, num_blocks, phys)
-    cell = L % bs
-    if isinstance(cache, PagedQuantKVCache):
-        kq, ks, vq, vs = _quantize_kv_writes(cache, k_new, v_new, kvq)
-        return type(cache)(
-            k_q=cache.k_q.at[phys, cell].set(kq, mode="drop"),
-            v_q=cache.v_q.at[phys, cell].set(vq, mode="drop"),
-            k_s=cache.k_s.at[phys, cell].set(ks, mode="drop"),
-            v_s=cache.v_s.at[phys, cell].set(vs, mode="drop"),
+    with jax.named_scope("kv_write"):
+        num_blocks, bs = cache.pos.shape
+        s_cap = paged_capacity(block_table, bs, window)
+        L = jnp.mod(jnp.maximum(pw, 0), s_cap)
+        phys = jnp.take_along_axis(block_table, L // bs, axis=1)  # (B, T)
+        dead = (pw < 0) | (phys < 0)
+        phys = jnp.where(dead, num_blocks, phys)
+        cell = L % bs
+        if isinstance(cache, PagedQuantKVCache):
+            kq, ks, vq, vs = _quantize_kv_writes(cache, k_new, v_new, kvq)
+            return type(cache)(
+                k_q=cache.k_q.at[phys, cell].set(kq, mode="drop"),
+                v_q=cache.v_q.at[phys, cell].set(vq, mode="drop"),
+                k_s=cache.k_s.at[phys, cell].set(ks, mode="drop"),
+                v_s=cache.v_s.at[phys, cell].set(vs, mode="drop"),
+                pos=cache.pos.at[phys, cell].set(pw, mode="drop"))
+        return PagedKVCache(
+            k=cache.k.at[phys, cell].set(k_new.astype(cache.k.dtype),
+                                         mode="drop"),
+            v=cache.v.at[phys, cell].set(v_new.astype(cache.v.dtype),
+                                         mode="drop"),
             pos=cache.pos.at[phys, cell].set(pw, mode="drop"))
-    return PagedKVCache(
-        k=cache.k.at[phys, cell].set(k_new.astype(cache.k.dtype),
-                                     mode="drop"),
-        v=cache.v.at[phys, cell].set(v_new.astype(cache.v.dtype),
-                                     mode="drop"),
-        pos=cache.pos.at[phys, cell].set(pw, mode="drop"))
 
 
 def paged_key_positions(block_table, q_pos, s_cap: int, block_size: int):
@@ -546,15 +548,16 @@ def reset_paged_lanes(cache, lane_mask, block_table):
     position masks the cell out of every read path). Works for unstacked
     (N, bs) and stacked (n_super, N, bs) arena layouts; the block table
     itself is host-owned (runtime.block_pool) and not touched here."""
-    num_blocks = cache.pos.shape[-2]
-    mask = jnp.asarray(lane_mask, bool)[:, None]
-    blocks = jnp.where(mask & (block_table >= 0), block_table,
-                       num_blocks).reshape(-1)
-    if cache.pos.ndim == 3:           # stacked scan leaf (n_super, N, bs)
-        pos = cache.pos.at[:, blocks].set(-1, mode="drop")
-    else:
-        pos = cache.pos.at[blocks].set(-1, mode="drop")
-    return cache._replace(pos=pos)
+    with jax.named_scope("kv_write"):
+        num_blocks = cache.pos.shape[-2]
+        mask = jnp.asarray(lane_mask, bool)[:, None]
+        blocks = jnp.where(mask & (block_table >= 0), block_table,
+                           num_blocks).reshape(-1)
+        if cache.pos.ndim == 3:       # stacked scan leaf (n_super, N, bs)
+            pos = cache.pos.at[:, blocks].set(-1, mode="drop")
+        else:
+            pos = cache.pos.at[blocks].set(-1, mode="drop")
+        return cache._replace(pos=pos)
 
 
 def reset_kv_lanes(cache, lane_mask, batch_axis: int = 0):
@@ -848,25 +851,26 @@ def attention_block(p, x, positions, cfg: AttnConfig, *, ctx=None,
         wmat = resolve_weight(p[name])
         return ctx.weight(f"{prefix}/{name}", wmat) if ctx is not None else wmat
 
-    if x_int8:
-        q = deploy_lib.matmul(x, p["wq"]).reshape(B, T, H, hd)
-        k = deploy_lib.matmul(x, p["wk"]).reshape(B, T, KV, hd)
-        v = deploy_lib.matmul(x, p["wv"]).reshape(B, T, KV, hd)
-    else:
-        q = (x @ w("wq")).reshape(B, T, H, hd)
-        k = (x @ w("wk")).reshape(B, T, KV, hd)
-        v = (x @ w("wv")).reshape(B, T, KV, hd)
-    if "q_norm" in p:   # qwen3-style per-head QK norm
-        from repro.models.common import rms_norm
-        q = rms_norm(q, p["q_norm"])
-        k = rms_norm(k, p["k_norm"])
-    if cfg.rope_theta is not None:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
-    if ctx is not None:
-        q = ctx.act(f"{prefix}/q", q)
-        k = ctx.act(f"{prefix}/k", k)
-        v = ctx.act(f"{prefix}/v", v)
+    with jax.named_scope("qkv"):
+        if x_int8:
+            q = deploy_lib.matmul(x, p["wq"]).reshape(B, T, H, hd)
+            k = deploy_lib.matmul(x, p["wk"]).reshape(B, T, KV, hd)
+            v = deploy_lib.matmul(x, p["wv"]).reshape(B, T, KV, hd)
+        else:
+            q = (x @ w("wq")).reshape(B, T, H, hd)
+            k = (x @ w("wk")).reshape(B, T, KV, hd)
+            v = (x @ w("wv")).reshape(B, T, KV, hd)
+        if "q_norm" in p:   # qwen3-style per-head QK norm
+            from repro.models.common import rms_norm
+            q = rms_norm(q, p["q_norm"])
+            k = rms_norm(k, p["k_norm"])
+        if cfg.rope_theta is not None:
+            q = apply_rope(q, positions, cfg.rope_theta)
+            k = apply_rope(k, positions, cfg.rope_theta)
+        if ctx is not None:
+            q = ctx.act(f"{prefix}/q", q)
+            k = ctx.act(f"{prefix}/k", k)
+            v = ctx.act(f"{prefix}/v", v)
 
     new_cache = None
     out = None
@@ -899,17 +903,19 @@ def attention_block(p, x, positions, cfg: AttnConfig, *, ctx=None,
             # (position p - S), which is exactly what earlier queries in
             # the chunk may still attend within their window.
             if append:
-                if paged:
-                    prev = _prev_positions(positions)
-                    k_past, v_past = paged_gather_kv(cache, block_table,
-                                                     cfg.window, kvq)
-                    kpos_past = paged_key_positions(block_table, prev, S,
-                                                    cache.pos.shape[1])
-                elif quantized:
-                    k_past, v_past = dequantize_kv(cache, kvq)
-                    kpos_past = cache.pos
-                else:
-                    k_past, v_past, kpos_past = cache.k, cache.v, cache.pos
+                with jax.named_scope("attend"):
+                    if paged:
+                        prev = _prev_positions(positions)
+                        k_past, v_past = paged_gather_kv(cache, block_table,
+                                                         cfg.window, kvq)
+                        kpos_past = paged_key_positions(
+                            block_table, prev, S, cache.pos.shape[1])
+                    elif quantized:
+                        k_past, v_past = dequantize_kv(cache, kvq)
+                        kpos_past = cache.pos
+                    else:
+                        k_past, v_past, kpos_past = (cache.k, cache.v,
+                                                     cache.pos)
             keep = min(T, S)
             kw, vw, pw = k[:, -keep:], v[:, -keep:], positions[:, -keep:]
             if paged:
@@ -919,9 +925,13 @@ def attention_block(p, x, positions, cfg: AttnConfig, *, ctx=None,
                 slots = _write_slots(pw, S, cfg.window)
                 new_cache = _write_kv(cache, kw, vw, pw, slots, bidx, kvq)
             if append:
-                k_att = jnp.concatenate([k_past.astype(k.dtype), k], axis=1)
-                v_att = jnp.concatenate([v_past.astype(v.dtype), v], axis=1)
-                kpos_att = jnp.concatenate([kpos_past, positions], axis=1)
+                with jax.named_scope("attend"):
+                    k_att = jnp.concatenate([k_past.astype(k.dtype), k],
+                                            axis=1)
+                    v_att = jnp.concatenate([v_past.astype(v.dtype), v],
+                                            axis=1)
+                    kpos_att = jnp.concatenate([kpos_past, positions],
+                                               axis=1)
             else:
                 k_att, v_att, kpos_att = k, v, positions
         elif paged:
@@ -930,29 +940,31 @@ def attention_block(p, x, positions, cfg: AttnConfig, *, ctx=None,
             # lane's blocks into a dense view + derived positions).
             new_cache = _write_paged_kv(cache, k, v, positions, block_table,
                                         cfg.window, kvq)
-            if quantized:
-                out = _paged_quant_decode_attend(q, new_cache, block_table,
-                                                 positions, cfg, ctx,
-                                                 prefix, kvq, dist=dist)
-            else:
-                out = _paged_decode_attend(q, new_cache, block_table,
-                                           positions, cfg, ctx, prefix,
-                                           dist=dist)
-            if out is None:
-                k_att, v_att = paged_gather_kv(new_cache, block_table,
-                                               cfg.window, kvq)
-                kpos_att = paged_key_positions(block_table, positions[:, 0],
-                                               S, cache.pos.shape[1])
+            with jax.named_scope("attend"):
+                if quantized:
+                    out = _paged_quant_decode_attend(
+                        q, new_cache, block_table, positions, cfg, ctx,
+                        prefix, kvq, dist=dist)
+                else:
+                    out = _paged_decode_attend(q, new_cache, block_table,
+                                               positions, cfg, ctx, prefix,
+                                               dist=dist)
+                if out is None:
+                    k_att, v_att = paged_gather_kv(new_cache, block_table,
+                                                   cfg.window, kvq)
+                    kpos_att = paged_key_positions(
+                        block_table, positions[:, 0], S, cache.pos.shape[1])
         else:
             # Decode: write the new token, attend over the cache.
             slots = _write_slots(positions, S, cfg.window)
             new_cache = _write_kv(cache, k, v, positions, slots, bidx, kvq)
             if quantized:
-                out = _quant_decode_attend(q, new_cache, positions, cfg,
-                                           ctx, prefix, kvq, dist=dist)
-                if out is None:       # kernel can't express: dequant + flash
-                    k_att, v_att = dequantize_kv(new_cache, kvq)
-                    kpos_att = new_cache.pos
+                with jax.named_scope("attend"):
+                    out = _quant_decode_attend(q, new_cache, positions, cfg,
+                                               ctx, prefix, kvq, dist=dist)
+                    if out is None:   # kernel can't express: dequant + flash
+                        k_att, v_att = dequantize_kv(new_cache, kvq)
+                        kpos_att = new_cache.pos
             else:
                 k_att, v_att, kpos_att = (new_cache.k, new_cache.v,
                                           new_cache.pos)
@@ -961,23 +973,25 @@ def attention_block(p, x, positions, cfg: AttnConfig, *, ctx=None,
         kpos_att = positions
 
     if out is None:
-        out = attend(q, k_att.astype(q.dtype), v_att.astype(q.dtype),
-                     jnp.broadcast_to(positions, (B, T)), kpos_att, cfg,
-                     ctx=ctx, prefix=prefix, chunked=chunked)
-    out2d = out.reshape(B, T, H * hd)
-    if x_int8:
-        wo_aq = ctx.deploy_act(f"{prefix}/wo_in")
-        if ctx.telemetry is not None:
-            ctx.telem_site(f"{prefix}/wo_in",
-                           deploy_lib.site_stats(out2d, wo_aq))
-        out = deploy_lib.matmul(deploy_lib.quantize_act(out2d, wo_aq),
-                                p["wo"])
-    else:
+        with jax.named_scope("attend"):
+            out = attend(q, k_att.astype(q.dtype), v_att.astype(q.dtype),
+                         jnp.broadcast_to(positions, (B, T)), kpos_att, cfg,
+                         ctx=ctx, prefix=prefix, chunked=chunked)
+    with jax.named_scope("out"):
+        out2d = out.reshape(B, T, H * hd)
+        if x_int8:
+            wo_aq = ctx.deploy_act(f"{prefix}/wo_in")
+            if ctx.telemetry is not None:
+                ctx.telem_site(f"{prefix}/wo_in",
+                               deploy_lib.site_stats(out2d, wo_aq))
+            out = deploy_lib.matmul(deploy_lib.quantize_act(out2d, wo_aq),
+                                    p["wo"])
+        else:
+            if ctx is not None:
+                out2d = ctx.act_in(f"{prefix}/wo_in", out2d)
+            out = out2d @ w("wo")
         if ctx is not None:
-            out2d = ctx.act_in(f"{prefix}/wo_in", out2d)
-        out = out2d @ w("wo")
-    if ctx is not None:
-        out = ctx.act(f"{prefix}/ctx_out", out)
+            out = ctx.act(f"{prefix}/ctx_out", out)
     return out, new_cache
 
 

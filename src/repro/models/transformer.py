@@ -231,24 +231,32 @@ def block_apply(cfg: ModelConfig, kind: str, p, x, positions, *, ctx=None,
             f"chunked (append) prefill supports attention blocks only, got "
             f"{kind!r} (recurrent state cannot replay earlier chunks)")
     if kind in ("attn", "local_attn"):
+        # named scopes (norm, attn/{qkv,attend,kv_write,out}, ffn) tag the
+        # device ops with the model code that issued them in a profile
         acfg = attn_cfg_for(cfg, kind)
-        h = _attn_input(cfg, p, x, ctx, prefix)
-        attn_out, new_cache = attention_block(
-            p["attn"], h, positions, acfg, ctx=ctx, prefix=f"{prefix}/attn",
-            cache=cache, chunked=chunked, block_table=block_table,
-            append=append, dist=dist)
+        with jax.named_scope("norm"):
+            h = _attn_input(cfg, p, x, ctx, prefix)
+        with jax.named_scope("attn"):
+            attn_out, new_cache = attention_block(
+                p["attn"], h, positions, acfg, ctx=ctx,
+                prefix=f"{prefix}/attn", cache=cache, chunked=chunked,
+                block_table=block_table, append=append, dist=dist)
         if cfg.post_norm:
-            attn_out = _norm(cfg, p["post_ln1"], attn_out)
+            with jax.named_scope("norm"):
+                attn_out = _norm(cfg, p["post_ln1"], attn_out)
         # the residual stream keeps its dtype (f32 on the integer path,
         # whose kernels emit f32; see _embed)
         x = x + attn_out.astype(x.dtype)
         if ctx is not None:
             x = ctx.act(f"{prefix}/residual_attn", x)
-        h = _ffn_input(cfg, p, x, ctx, prefix)
-        ffn_out = _ffn_apply(cfg, p.get("moe", p.get("ffn")), h, ctx=ctx,
-                             prefix=f"{prefix}/ffn", dist=dist)
+        with jax.named_scope("norm"):
+            h = _ffn_input(cfg, p, x, ctx, prefix)
+        with jax.named_scope("ffn"):
+            ffn_out = _ffn_apply(cfg, p.get("moe", p.get("ffn")), h,
+                                 ctx=ctx, prefix=f"{prefix}/ffn", dist=dist)
         if cfg.post_norm:
-            ffn_out = _norm(cfg, p["post_ln2"], ffn_out)
+            with jax.named_scope("norm"):
+                ffn_out = _norm(cfg, p["post_ln2"], ffn_out)
         if ctx is not None:
             ffn_out = ctx.act(f"{prefix}/ffn_out", ffn_out)
         x = x + ffn_out.astype(x.dtype)
@@ -896,7 +904,8 @@ def forward(cfg: ModelConfig, params, tokens, *, embeds=None, ctx=None,
 def _forward(cfg: ModelConfig, params, tokens, *, embeds, ctx, dist, cache,
              positions, remat, chunked, append):
     B, T = tokens.shape
-    x = _embed(cfg, params, tokens, embeds, ctx, dist=dist)
+    with jax.named_scope("embed"):
+        x = _embed(cfg, params, tokens, embeds, ctx, dist=dist)
     T_full = x.shape[1]
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(T_full, dtype=jnp.int32),
@@ -926,7 +935,8 @@ def _forward(cfg: ModelConfig, params, tokens, *, embeds, ctx, dist, cache,
             new_cache = {"layers": new_layer_caches}
             if block_table is not None:
                 new_cache["block_table"] = block_table
-        logits = _head(cfg, params, x, ctx, dist=dist)
+        with jax.named_scope("head"):
+            logits = _head(cfg, params, x, ctx, dist=dist)
         return logits, new_cache
 
     # stacked scan path
@@ -968,14 +978,17 @@ def _forward(cfg: ModelConfig, params, tokens, *, embeds, ctx, dist, cache,
         return x, (new_c, tel_ys)
 
     # lax.scan needs xs leaves with a leading axis; pack params (+caches).
-    if cache is not None:
-        x, (new_scan_caches, tel_stacked) = jax.lax.scan(
-            lambda carry, xs_: scan_fn(carry, xs_),
-            x, (params["scan"], scan_caches))
-    else:
-        x, (_, tel_stacked) = jax.lax.scan(
-            lambda carry, p: scan_fn(carry, (p,)), x, params["scan"])
-        new_scan_caches = None
+    # The "layers" scope also names the scan's own ops: the per-layer
+    # slicing of the stacked params/caches and the stacking of the new ones.
+    with jax.named_scope("layers"):
+        if cache is not None:
+            x, (new_scan_caches, tel_stacked) = jax.lax.scan(
+                lambda carry, xs_: scan_fn(carry, xs_),
+                x, (params["scan"], scan_caches))
+        else:
+            x, (_, tel_stacked) = jax.lax.scan(
+                lambda carry, p: scan_fn(carry, (p,)), x, params["scan"])
+            new_scan_caches = None
     if telem is not None:
         telem.update(tel_stacked)
 
@@ -994,7 +1007,8 @@ def _forward(cfg: ModelConfig, params, tokens, *, embeds, ctx, dist, cache,
         new_cache = {"scan": new_scan_caches, "tail": new_tail_caches}
         if block_table is not None:
             new_cache["block_table"] = block_table
-    logits = _head(cfg, params, x, ctx, dist=dist)
+    with jax.named_scope("head"):
+        logits = _head(cfg, params, x, ctx, dist=dist)
     return logits, new_cache
 
 

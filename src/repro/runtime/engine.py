@@ -100,6 +100,10 @@ class Engine:
     Steps built with ``quant_telemetry=True`` return an extra telemetry
     dict; the engine folds it into ``telemetry_sink`` (when given) and
     hands back the plain outputs, so callers never see the arity change.
+    With a ``tracer`` (runtime.telemetry.Tracer) each fused op records a
+    ``dispatch`` span (host inputs placed, the jitted call returned) and a
+    ``readback`` span (the greedy argmax and its device-to-host copy)
+    inside the caller's span.
 
     Only greedy (argmax) decoding is implemented — the parity property
     "continuous == static == async == served alone, token for token" is
@@ -118,7 +122,8 @@ class Engine:
                  swap_in_fn: Optional[Callable] = None,
                  copy_block_fn: Optional[Callable] = None,
                  dist=None,
-                 telemetry_sink: Optional[Callable[[Dict], None]] = None):
+                 telemetry_sink: Optional[Callable[[Dict], None]] = None,
+                 tracer=None):
         if batch_slots < 1:
             raise ValueError(f"batch_slots must be >= 1, got {batch_slots}")
         self.admit_fn = admit_fn
@@ -133,6 +138,7 @@ class Engine:
         self.max_len = max_len
         self.dist = dist
         self.telemetry_sink = telemetry_sink
+        self.tracer = tracer
         # trace-time counters: engine-internal jits bump these from inside
         # the traced python body, so a recompile is observable as a count
         # > 1 (make_engine extends this to the step functions themselves)
@@ -172,6 +178,19 @@ class Engine:
         np conversion blocks on the device computation."""
         return np.asarray(jnp.argmax(logits[:, -1:], axis=-1), np.int32)
 
+    def _run(self, fn, host_inputs, cache):
+        """One fused op: place the host inputs, call ``fn`` and read the
+        greedy tokens back. Returns ((B, 1) tokens, cache)."""
+        if self.tracer is None:
+            logits, cache = self._unwrap(
+                fn(*[self._put(x) for x in host_inputs], cache))
+            return self._greedy(logits), cache
+        with self.tracer.span("dispatch"):
+            logits, cache = self._unwrap(
+                fn(*[self._put(x) for x in host_inputs], cache))
+        with self.tracer.span("readback"):
+            return self._greedy(logits), cache
+
     # -- state -------------------------------------------------------------
 
     def init_state(self) -> DecodeState:
@@ -188,10 +207,8 @@ class Engine:
         packed prompts in one model call. Returns ((B,1) greedy first
         tokens, cache) — semantically ``insert(prefill(r), slot)`` for every
         masked lane, in one step."""
-        logits, cache = self._unwrap(self.admit_fn(
-            self._put(tokens), self._put(positions), self._put(admit_mask),
-            cache))
-        return self._greedy(logits), cache
+        return self._run(self.admit_fn, (tokens, positions, admit_mask),
+                         cache)
 
     def chunk(self, tokens, positions, reset_mask, cache):
         """One append-mode chunked-prefill step (see
@@ -199,18 +216,15 @@ class Engine:
         tokens from the chunk's final position, cache)."""
         if self.chunk_fn is None:
             raise ValueError("engine was built without a chunk_fn")
-        logits, cache = self._unwrap(self.chunk_fn(
-            self._put(tokens), self._put(positions), self._put(reset_mask),
-            cache))
-        return self._greedy(logits), cache
+        return self._run(self.chunk_fn, (tokens, positions, reset_mask),
+                         cache)
 
     def generate(self, state: DecodeState):
         """One greedy decode step over every lane. Returns ((B,1) per-lane
         next tokens, cache); idle (pos -1) lanes produce garbage tokens the
         policy layer ignores, and their cache writes are position-dropped."""
-        logits, cache = self._unwrap(self.decode_fn(
-            self._put(state.tokens), self._put(state.pos), state.cache))
-        return self._greedy(logits), cache
+        return self._run(self.decode_fn, (state.tokens, state.pos),
+                         state.cache)
 
     # -- paged plumbing (over-commit preemption, prefix COW) ----------------
 

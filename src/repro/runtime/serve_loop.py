@@ -62,6 +62,7 @@ decodes exactly as if it were served alone.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import time
 from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
@@ -74,6 +75,9 @@ from repro.runtime.block_pool import BlockPool, blocks_for_tokens
 from repro.runtime.engine import DecodeState, Engine
 from repro.runtime.radix_cache import RadixCache
 from repro.runtime.telemetry import ServeTelemetry
+
+# what a span site enters when tracing is off (reusable: it keeps no state)
+_NO_SPAN = contextlib.nullcontext()
 
 
 @dataclasses.dataclass
@@ -711,7 +715,8 @@ class Scheduler:
             swap_in_fn=swap_in_fn, copy_block_fn=copy_block_fn,
             telemetry_sink=(telemetry.quant.update
                             if telemetry is not None
-                            and telemetry.quant is not None else None))
+                            and telemetry.quant is not None else None),
+            tracer=self._tracer)
 
     def run(self, requests: List[Request]) -> ServeStats:
         _check_capacity(requests, self.max_len, self.pool, self._ring_tokens)
@@ -758,12 +763,16 @@ class Scheduler:
             before = (book.step, stats.preemptions)
             free = [i for i in range(B) if lanes[i] is None]
             if queue and self.over_commit:
-                state = self._admit_over_commit(lanes, state, book)
+                with self._span("admission"):
+                    state = self._admit_over_commit(lanes, state, book)
             elif free and queue and self._head_fits(queue[0].req):
                 if self.prefill_chunk is None and self.radix is None:
-                    state = self._admit(free, queue, pad, lanes, state, book)
+                    with self._span("admission"):
+                        state = self._admit(free, queue, pad, lanes, state,
+                                            book)
                     continue    # immediate retirees may have freed lanes
-                self._admit_chunked(free, queue, lanes, book)
+                with self._span("admission"):
+                    self._admit_chunked(free, queue, lanes, book)
             prefilling = any(off is not None for off in self._pref)
             has_decodable = any(lanes[i] is not None and self._pref[i] is None
                                 for i in range(B))
@@ -954,7 +963,8 @@ class Scheduler:
         step's outputs."""
         if self.pool is not None and self.pool.dirty \
                 and isinstance(cache, dict):
-            cache["block_table"] = jnp.asarray(self.pool.table)
+            with self._span("table", table_uploads=1):
+                cache["block_table"] = jnp.asarray(self.pool.table)
             self.pool.dirty = False
 
     def _track(self, cache, lanes, state: DecodeState, book: _Book) -> None:
@@ -983,13 +993,21 @@ class Scheduler:
             self._tracer.event(name, self._book.step, rid=rid, lane=lane,
                                **args)
 
+    def _span(self, name: str, **args):
+        """A tracer span at the current step (a stateless no-op context
+        when tracing is off)."""
+        if self._tracer is None:
+            return _NO_SPAN
+        return self._tracer.span(name, self._book.step, **args)
+
     def _step_call(self, phase: str, op: Callable, args,
                    n_lanes: Optional[int] = None):
         """One engine op (a jitted model call plus greedy readback). Under
-        tracing it becomes a phase duration event — the op's host-side
-        token conversion already blocks on device execution, so the
-        duration covers the computation, not just dispatch. Telemetry
-        unwrapping happens inside the engine (telemetry_sink)."""
+        tracing it becomes a phase span holding the engine's dispatch and
+        readback spans — the readback's host-side token conversion blocks
+        on device execution, so the duration covers the computation, not
+        just dispatch. Telemetry unwrapping happens inside the engine
+        (telemetry_sink)."""
         if self._tracer is None:
             return op(*args)
         with self._tracer.phase(phase, self._book.step) as ph:
@@ -1376,51 +1394,46 @@ class Scheduler:
             # already visited, or the demander itself), changing who
             # chunks this step
             bs = self.pool.block_size
-            for i in range(B):
-                if self._pref[i] is None or lanes[i] is None:
-                    continue
-                off = self._pref[i]
-                seq = self._lane_prompt[i]
-                c = min(C, len(seq) - off)
-                # copy-on-write BEFORE growth/sync: a ring-window write in
-                # this chunk may wrap into a shared prefix column
-                if self.radix is not None:
-                    cache = self._cow_barrier(i, range(off, off + c), cache,
-                                              lanes, state, book)
-                    if lanes[i] is None:
-                        continue    # preempted inside the COW barrier
-                # map the blocks this chunk's writes land in (reservation-
-                # backed, cannot fail mid-flight — unless over-commit,
-                # which grows on demand and preempts when the pool is dry)
-                n_total = (off + c - 1) // bs + 1
-                if self._ring_blocks is not None:
-                    n_total = min(n_total, self._ring_blocks)
-                n_before = (self.pool.lane_mapped(i)
-                            if self._tracer is not None else 0)
-                if self.over_commit:
-                    self._ensure_blocks(i, n_total, lanes, state, book)
-                else:
-                    self.pool.grow(i, n_total)
-                if self._tracer is not None and lanes[i] is not None \
-                        and self.pool.lane_mapped(i) > n_before:
-                    self._ev("block_grow", rid=lanes[i].rid, lane=i,
-                             blocks=self.pool.lane_mapped(i) - n_before)
+            with self._span("pool"):
+                for i in range(B):
+                    if self._pref[i] is None or lanes[i] is None:
+                        continue
+                    off = self._pref[i]
+                    seq = self._lane_prompt[i]
+                    c = min(C, len(seq) - off)
+                    # copy-on-write BEFORE growth/sync: a ring-window write
+                    # in this chunk may wrap into a shared prefix column
+                    if self.radix is not None:
+                        cache = self._cow_barrier(i, range(off, off + c),
+                                                  cache, lanes, state, book)
+                        if lanes[i] is None:
+                            continue    # preempted inside the COW barrier
+                    # map the blocks this chunk's writes land in
+                    # (reservation-backed, cannot fail mid-flight — unless
+                    # over-commit, which grows on demand and preempts when
+                    # the pool is dry)
+                    n_total = (off + c - 1) // bs + 1
+                    if self._ring_blocks is not None:
+                        n_total = min(n_total, self._ring_blocks)
+                    self._grow(i, n_total, lanes, state, book)
         prefilling = [i for i in range(B) if self._pref[i] is not None]
         if not prefilling:          # every prefilling lane was preempted
             return DecodeState(state.tokens, state.pos, cache)
-        toks = np.zeros((B, C), np.int32)
-        posm = np.full((B, C), -1, np.int32)
-        reset = np.zeros((B,), bool)
-        ends = {}
-        for i in prefilling:
-            off = self._pref[i]
-            seq = self._lane_prompt[i] if self._lane_prompt[i] is not None \
-                else lanes[i].prompt
-            c = min(C, len(seq) - off)
-            toks[i, C - c:] = seq[off:off + c]
-            posm[i, C - c:] = np.arange(off, off + c, dtype=np.int32)
-            reset[i] = off == 0
-            ends[i] = off + c
+        with self._span("inputs"):
+            toks = np.zeros((B, C), np.int32)
+            posm = np.full((B, C), -1, np.int32)
+            reset = np.zeros((B,), bool)
+            ends = {}
+            for i in prefilling:
+                off = self._pref[i]
+                seq = self._lane_prompt[i] \
+                    if self._lane_prompt[i] is not None else lanes[i].prompt
+                c = min(C, len(seq) - off)
+                toks[i, C - c:] = seq[off:off + c]
+                posm[i, C - c:] = np.arange(off, off + c, dtype=np.int32)
+                reset[i] = off == 0
+                ends[i] = off + c
+            tokens, pos = state.tokens.copy(), state.pos.copy()
         self._sync_table(cache)
         last, cache = self._step_call(
             "chunk", self.engine.chunk,
@@ -1428,27 +1441,44 @@ class Scheduler:
         book.stats.prefill_calls += 1
         book.stats.chunk_steps += 1
         book.step += 1
-        tokens, pos = state.tokens.copy(), state.pos.copy()
-        for i in prefilling:
-            r = lanes[i]
-            seq = self._lane_prompt[i] if self._lane_prompt[i] is not None \
-                else r.prompt
-            if ends[i] < len(seq):
-                self._pref[i] = ends[i]     # more chunks to go
-                continue
-            self._pref[i] = None            # last chunk: lane is decodable
-            tokens[i, 0] = last[i, 0]
-            pos[i, 0] = len(seq)
-            book.emit(r, tokens[i, 0])
-        # sample gauges BEFORE releasing quota-1 retirees (as in _admit)
-        self._track(cache, lanes, DecodeState(tokens, pos, cache), book)
-        for i in prefilling:
-            if self._pref[i] is None and lanes[i].done:
+        with self._span("emit"):
+            for i in prefilling:
                 r = lanes[i]
-                lanes[i] = None             # quota 1: retire immediately
-                pos[i, 0] = -1
-                self._release(i, r)
+                seq = self._lane_prompt[i] \
+                    if self._lane_prompt[i] is not None else r.prompt
+                if ends[i] < len(seq):
+                    self._pref[i] = ends[i]     # more chunks to go
+                    continue
+                self._pref[i] = None            # last chunk: decodable
+                tokens[i, 0] = last[i, 0]
+                pos[i, 0] = len(seq)
+                book.emit(r, tokens[i, 0])
+        with self._span("retirement"):
+            # sample gauges BEFORE releasing quota-1 retirees (as in _admit)
+            self._track(cache, lanes, DecodeState(tokens, pos, cache), book)
+            for i in prefilling:
+                if self._pref[i] is None and lanes[i].done:
+                    r = lanes[i]
+                    lanes[i] = None         # quota 1: retire immediately
+                    pos[i, 0] = -1
+                    self._release(i, r)
         return DecodeState(tokens, pos, cache)
+
+    def _grow(self, lane: int, n_total: int, lanes, state: DecodeState,
+              book: _Book) -> None:
+        """Map ``lane``'s blocks up to ``n_total`` (over-commit: growing on
+        demand, preempting when the pool is dry). Under tracing, a growth
+        emits a ``block_grow`` event."""
+        n_before = (self.pool.lane_mapped(lane)
+                    if self._tracer is not None else 0)
+        if self.over_commit:
+            self._ensure_blocks(lane, n_total, lanes, state, book)
+        else:
+            self.pool.grow(lane, n_total)
+        if self._tracer is not None and lanes[lane] is not None \
+                and self.pool.lane_mapped(lane) > n_before:
+            self._ev("block_grow", rid=lanes[lane].rid, lane=lane,
+                     blocks=self.pool.lane_mapped(lane) - n_before)
 
     def _decode(self, lanes, state: DecodeState, book: _Book) -> DecodeState:
         cache = state.cache
@@ -1458,56 +1488,51 @@ class Scheduler:
             # growth may PREEMPT a lane instead (possibly the demander),
             # so the active set is recomputed after this pre-pass.
             bs = self.pool.block_size
-            for i in range(self.batch_slots):
-                if lanes[i] is None or self._pref[i] is not None:
-                    continue
-                p = int(state.pos[i, 0])
-                if self.radix is not None:
-                    # a ring-window write may wrap into a shared column
-                    cache = self._cow_barrier(i, (p,), cache,
-                                              lanes, state, book)
-                    if lanes[i] is None:
-                        continue    # preempted inside the COW barrier
-                n_total = p // bs + 1
-                if self._ring_blocks is not None:
-                    n_total = min(n_total, self._ring_blocks)
-                n_before = (self.pool.lane_mapped(i)
-                            if self._tracer is not None else 0)
-                if self.over_commit:
-                    self._ensure_blocks(i, n_total, lanes, state, book)
-                else:
-                    self.pool.grow(i, n_total)
-                if self._tracer is not None and lanes[i] is not None \
-                        and self.pool.lane_mapped(i) > n_before:
-                    self._ev("block_grow", rid=lanes[i].rid, lane=i,
-                             blocks=self.pool.lane_mapped(i) - n_before)
+            with self._span("pool"):
+                for i in range(self.batch_slots):
+                    if lanes[i] is None or self._pref[i] is not None:
+                        continue
+                    p = int(state.pos[i, 0])
+                    if self.radix is not None:
+                        # a ring-window write may wrap into a shared column
+                        cache = self._cow_barrier(i, (p,), cache,
+                                                  lanes, state, book)
+                        if lanes[i] is None:
+                            continue    # preempted inside the COW barrier
+                    n_total = p // bs + 1
+                    if self._ring_blocks is not None:
+                        n_total = min(n_total, self._ring_blocks)
+                    self._grow(i, n_total, lanes, state, book)
             self._sync_table(cache)
         active = [i for i, r in enumerate(lanes)
                   if r is not None and self._pref[i] is None]
         if not active:              # every decodable lane was preempted
             return DecodeState(state.tokens, state.pos, cache)
+        with self._span("inputs"):
+            tokens, pos = state.tokens.copy(), state.pos.copy()
         nxt, cache = self._step_call(
             "decode_batch", self.engine.generate,
             (DecodeState(state.tokens, state.pos, cache),),
             n_lanes=len(active))
         book.count_decode(len(active))
         book.step += 1
-        tokens, pos = state.tokens.copy(), state.pos.copy()
-        for i in active:
-            r = lanes[i]
-            tokens[i, 0] = nxt[i, 0]
-            pos[i, 0] += 1
-            book.emit(r, tokens[i, 0])
-        # sample gauges BEFORE releasing retirees: a lane whose final write
-        # just grew a block still holds it during this step, and the peak
-        # must include it
-        self._track(cache, lanes, DecodeState(tokens, pos, cache), book)
-        for i in active:
-            if lanes[i].done:
+        with self._span("emit"):
+            for i in active:
                 r = lanes[i]
-                lanes[i] = None
-                pos[i, 0] = -1
-                self._release(i, r)
+                tokens[i, 0] = nxt[i, 0]
+                pos[i, 0] += 1
+                book.emit(r, tokens[i, 0])
+        with self._span("retirement"):
+            # sample gauges BEFORE releasing retirees: a lane whose final
+            # write just grew a block still holds it during this step, and
+            # the peak must include it
+            self._track(cache, lanes, DecodeState(tokens, pos, cache), book)
+            for i in active:
+                if lanes[i].done:
+                    r = lanes[i]
+                    lanes[i] = None
+                    pos[i, 0] = -1
+                    self._release(i, r)
         return DecodeState(tokens, pos, cache)
 
 

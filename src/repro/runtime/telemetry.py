@@ -9,10 +9,14 @@ every hot-loop callsite a no-op):
   chunk, decode-batch, block grow, COW, preempt/swap/drop, resume,
   radix-evict, retire) with the scheduler step index plus a wall-clock
   timestamp, exported as Chrome-trace-event JSON (load the file in
-  https://ui.perfetto.dev). Phases (admit/chunk/decode/swap) are duration
-  events on a "steps" track; each request becomes a span on its lane's
-  track, so the Perfetto timeline shows lane occupancy directly. Per-phase
-  step-latency histograms (p50/p95/p99) ride along.
+  https://ui.perfetto.dev). Spans (the model-call phases admit/chunk/
+  decode_batch/swap and the host work between calls: admission, pool,
+  inputs, table, dispatch, readback, emit, retirement) are nested duration
+  events on a "steps" track, and each also enters a
+  ``jax.profiler.TraceAnnotation("serve:<name>")``, so a ``jax.profiler``
+  trace shows it on the device ops' clock. Each request becomes a span on
+  its lane's track, so the Perfetto timeline shows lane occupancy
+  directly. Per-span latency histograms (p50/p95/p99) ride along.
 - :class:`MetricsLogger` — periodic gauge snapshots (queue depth, resident
   lanes, free/evictable blocks, refcount totals, prefix hit rate,
   preemption counters) appended as JSON-lines, plus a final Prometheus
@@ -33,6 +37,7 @@ import json
 import time
 from typing import Any, Callable, Dict, List, Optional, TextIO, Tuple
 
+import jax
 import numpy as np
 
 # Event names (the taxonomy in docs/observability.md). Phase events carry a
@@ -41,19 +46,24 @@ PHASES = ("admit", "chunk", "decode_batch", "swap_out", "swap_in")
 EVENTS = ("enqueue", "admit", "prefix_hit", "chunk", "decode_batch",
           "block_grow", "cow", "preempt", "swap_out", "drop", "resume",
           "radix_evict", "retire")
+# prefix of every span's name in a jax.profiler trace
+ANNOTATION_PREFIX = "serve:"
 
 
 @dataclasses.dataclass
 class TraceEvent:
     """One lifecycle event. ``ts`` is seconds since tracer start (exported
-    as µs); ``step`` is the scheduler's monotonic step index."""
+    as µs); ``step`` is the scheduler's monotonic step index. Spans carry
+    their own ``sid`` and the ``parent`` sid of the span enclosing them."""
     name: str
     step: int
     ts: float
     rid: Optional[int] = None      # request id, when request-scoped
     lane: Optional[int] = None     # decode lane (slot), when resident
-    dur: float = 0.0               # seconds; > 0 only for phase events
+    dur: float = 0.0               # seconds; > 0 only for spans
     args: Optional[Dict[str, Any]] = None
+    sid: Optional[int] = None      # span id (spans only)
+    parent: Optional[int] = None   # enclosing span's sid
 
 
 def _percentiles(xs: List[float]) -> Dict[str, float]:
@@ -69,15 +79,19 @@ def _percentiles(xs: List[float]) -> Dict[str, float]:
 class Tracer:
     """Low-overhead lifecycle event recorder with Chrome-trace export.
 
-    Record with :meth:`event` (instant) and :meth:`phase` (timed context
-    manager around a jitted call). The scheduler holds ``tracer=None`` when
-    tracing is off, so the disabled path never constructs one of these.
+    Record with :meth:`event` (instant) and :meth:`span` (timed context
+    manager; :meth:`phase` is the span around a jitted call). Spans nest:
+    the parent of a span is the span open around it on the scheduler
+    thread. The scheduler holds ``tracer=None`` when tracing is off, so the
+    disabled path never constructs one of these.
     """
 
     def __init__(self) -> None:
         self.events: List[TraceEvent] = []
         self._t0 = time.perf_counter()
         self._phase_s: Dict[str, List[float]] = {p: [] for p in PHASES}
+        self._open: List["_PhaseTimer"] = []     # spans open, outermost first
+        self._sids = 0
 
     # -- recording ---------------------------------------------------------
     def now(self) -> float:
@@ -88,13 +102,24 @@ class Tracer:
         self.events.append(TraceEvent(name, step, self.now(), rid=rid,
                                       lane=lane, args=args or None))
 
+    def span(self, name: str, step: Optional[int] = None,
+             **args: Any) -> "_PhaseTimer":
+        """A timed span, used as a context manager: on exit it records a
+        duration event with ``args`` (the caller may add to ``.args``
+        inside). ``step`` None takes the enclosing span's step."""
+        if step is None:
+            step = self._open[-1].step if self._open else 0
+        return _PhaseTimer(self, name, step, args)
+
     def phase(self, name: str, step: int) -> "_PhaseTimer":
-        return _PhaseTimer(self, name, step)
+        return self.span(name, step)
 
     def _end_phase(self, name: str, step: int, t_start: float,
-                   dur: float, args: Optional[Dict[str, Any]]) -> None:
+                   dur: float, args: Optional[Dict[str, Any]],
+                   sid: Optional[int] = None,
+                   parent: Optional[int] = None) -> None:
         self.events.append(TraceEvent(name, step, t_start, dur=dur,
-                                      args=args))
+                                      args=args, sid=sid, parent=parent))
         self._phase_s.setdefault(name, []).append(dur)
 
     # -- analysis ----------------------------------------------------------
@@ -180,6 +205,10 @@ class Tracer:
             base = {"name": e.name, "pid": 1,
                     "ts": e.ts * 1e6, "args": dict(e.args or {})}
             base["args"]["step"] = e.step
+            if e.parent is not None:
+                base["args"]["parent"] = e.parent
+            if e.sid is not None:
+                base["args"]["sid"] = e.sid
             if e.rid is not None:
                 base["args"]["rid"] = e.rid
             if e.dur > 0.0:                       # phase duration event
@@ -196,22 +225,37 @@ class Tracer:
 
 
 class _PhaseTimer:
-    """Times one jitted phase call; use as a context manager. The caller is
-    expected to block_until_ready inside the ``with`` so the duration covers
-    device time, not just dispatch."""
+    """One span of a :class:`Tracer`; use as a context manager. It times
+    the host's time inside the ``with`` (a jitted call returns before the
+    device finishes, so around a model call it covers the device time only
+    when the caller waits for a result inside it, as the engine's greedy
+    read-back does) and holds a ``serve:<name>`` profiler annotation open
+    for as long."""
 
-    def __init__(self, tracer: Tracer, name: str, step: int) -> None:
-        self._tracer, self._name, self._step = tracer, name, step
-        self.args: Dict[str, Any] = {}
+    def __init__(self, tracer: Tracer, name: str, step: int,
+                 args: Optional[Dict[str, Any]] = None) -> None:
+        self._tracer, self._name, self.step = tracer, name, step
+        self.args: Dict[str, Any] = args or {}
 
     def __enter__(self) -> "_PhaseTimer":
-        self._start = self._tracer.now()
+        tr = self._tracer
+        self._parent = tr._open[-1].sid if tr._open else None
+        tr._sids += 1
+        self.sid = tr._sids
+        tr._open.append(self)
+        self._annotation = jax.profiler.TraceAnnotation(
+            ANNOTATION_PREFIX + self._name)
+        self._annotation.__enter__()
+        self._start = tr.now()
         return self
 
     def __exit__(self, *exc) -> None:
-        dur = self._tracer.now() - self._start
-        self._tracer._end_phase(self._name, self._step, self._start, dur,
-                                self.args or None)
+        tr = self._tracer
+        dur = tr.now() - self._start
+        self._annotation.__exit__(*exc)
+        tr._open.pop()
+        tr._end_phase(self._name, self.step, self._start, dur,
+                      self.args or None, self.sid, self._parent)
 
 
 class MetricsLogger:

@@ -21,6 +21,7 @@ Coverage:
   traced step signatures are unchanged — tracing is host-side only).
 """
 import json
+import re
 
 import jax
 import jax.numpy as jnp
@@ -294,6 +295,75 @@ class TestRecompileGuard:
         for a, b in zip(plain, traced):
             assert a.tokens_out == b.tokens_out
 
+    def test_named_scopes_add_no_retrace(self):
+        """A real (reduced) model on the paged, chunked path: its steps
+        carry the named scopes (attn/qkv, attn/attend, attn/kv_write,
+        ffn, head) in their compiled ops' metadata, serving untraced then
+        traced reuses the one trace of each step, and the greedy tokens
+        are identical."""
+        from repro.configs import get_config
+        from repro.models import transformer as tfm
+        from repro.runtime.steps import (make_chunk_prefill_step,
+                                         make_decode_step)
+        cfg = get_config("gemma2-2b").reduced()
+        params = tfm.init_params(cfg, jax.random.PRNGKey(0), stacked=True,
+                                 dtype=jnp.float32)
+        traces = {"chunk": 0, "decode": 0}
+
+        def counted(name, fn):
+            def step(*args):
+                traces[name] += 1
+                return fn(*args)
+            return jax.jit(step)
+
+        chunk_j = counted("chunk", make_chunk_prefill_step(cfg))
+        decode_j = counted("decode", make_decode_step(cfg))
+        max_len, bs, lanes = 32, 4, 2
+        nb = tfm.paged_lane_blocks(cfg, max_len, bs)
+
+        def init(b):
+            return tfm.init_cache(cfg, b, max_len, dtype=jnp.float32,
+                                  paged=True, block_size=bs,
+                                  num_blocks=lanes * nb, mapped=False)
+
+        def run(tel):
+            rng = np.random.RandomState(3)
+            reqs = [Request(rid=i, prompt=rng.randint(
+                1, cfg.vocab_size, size=n).astype(np.int32),
+                max_new_tokens=q) for i, (n, q) in
+                enumerate([(5, 4), (9, 3), (3, 5)])]
+            serve_continuous(
+                lambda *a: None,
+                lambda t, p, c: decode_j(params, t, p, c), init, reqs,
+                batch_slots=lanes, max_len=max_len,
+                block_pool=BlockPool(lanes * nb, bs, lanes, nb),
+                chunk_fn=lambda t, pm, m, c: chunk_j(params, t, pm, m, c),
+                prefill_chunk=4,
+                write_caps=tfm.attn_write_caps(cfg, max_len, bs),
+                ring_tokens=tfm.paged_ring_tokens(cfg, max_len, bs),
+                telemetry=tel)
+            return [r.tokens_out for r in reqs]
+
+        plain = run(None)
+        assert traces == {"chunk": 1, "decode": 1}
+        tel = ServeTelemetry.create(trace=True)
+        assert run(tel) == plain
+        assert traces == {"chunk": 1, "decode": 1}     # zero new traces
+        names = {e.name for e in tel.tracer.events}
+        assert {"chunk", "decode_batch", "dispatch", "readback", "table",
+                "pool", "inputs", "emit", "retirement"} <= names
+        cache = init(lanes)
+        hlo = jax.jit(make_decode_step(cfg)).lower(
+            params, jnp.zeros((lanes, 1), jnp.int32),
+            jnp.zeros((lanes, 1), jnp.int32), cache).compile().as_text()
+        for scope in ("/embed/", "/norm/", "/attn/qkv/", "/attn/attend/",
+                      "/attn/kv_write/", "/attn/out/", "/ffn/", "/head/",
+                      "/layers/"):
+            assert scope in hlo, scope
+        # the layer scan's own slicing of the stacked params and caches
+        assert re.search(r'op_name="[^"]*/layers/while/body/'
+                         r'dynamic_(update_)?slice"', hlo)
+
     def test_disabled_telemetry_returns_plain_step(self):
         """quant_telemetry=False hands back the ORIGINAL 2-output closure
         (not a wrapper), so existing jit caches keyed on it stay warm."""
@@ -328,3 +398,145 @@ class TestTracerUnit:
         assert hit["args"]["rid"] == 7
         assert hit["tid"] == 1                   # lane 0 -> tid 1
         json.dumps(doc)                          # serializable end-to-end
+
+    def test_span_nesting_args_and_step(self):
+        """Spans nest by the scheduler thread's open spans (parent sid,
+        inherited step), carry their args, and a phase is a span whose
+        Chrome-trace event and latency histogram are those of before."""
+        tr = Tracer()
+        with tr.span("pool", 3, blocks=2):
+            with tr.span("table", table_uploads=1):
+                pass
+        with tr.phase("decode_batch", 4) as ph:
+            ph.args["lanes"] = 2
+            with tr.span("dispatch"):
+                pass
+            with tr.span("readback"):
+                pass
+        by = {e.name: e for e in tr.events}
+        assert [e.name for e in tr.events] == [
+            "table", "pool", "dispatch", "readback", "decode_batch"]
+        assert by["pool"].parent is None and by["pool"].step == 3
+        assert by["pool"].args == {"blocks": 2}
+        assert by["table"].parent == by["pool"].sid
+        assert by["table"].step == 3 and by["table"].args == {
+            "table_uploads": 1}
+        for child in ("dispatch", "readback"):
+            assert by[child].parent == by["decode_batch"].sid
+            assert by[child].step == 4
+        ph_ev = by["decode_batch"]
+        assert by["dispatch"].dur + by["readback"].dur <= ph_ev.dur
+        # the old phase: one X event on the steps track with its args
+        doc = tr.to_chrome_trace()
+        (x,) = [e for e in doc["traceEvents"]
+                if e["name"] == "decode_batch"]
+        assert x["ph"] == "X" and x["tid"] == 0
+        assert x["args"]["lanes"] == 2 and x["args"]["step"] == 4
+        assert x["dur"] == pytest.approx(ph_ev.dur * 1e6)
+        hist = tr.latency_histograms()
+        assert hist["decode_batch"]["n"] == 1
+        assert set(hist) == {"decode_batch", "pool", "table", "dispatch",
+                             "readback"}
+        json.dumps(doc)
+
+    def test_spans_enter_profiler_annotations(self, monkeypatch):
+        """Each span holds a ``serve:<name>`` profiler annotation open for
+        its duration, properly nested."""
+        log = []
+
+        class Recording:
+            def __init__(self, name, **kw):
+                self.name = name
+
+            def __enter__(self):
+                log.append(("enter", self.name))
+
+            def __exit__(self, *exc):
+                log.append(("exit", self.name))
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recording)
+        tr = Tracer()
+        with tr.phase("chunk", 1):
+            with tr.span("readback"):
+                pass
+        assert log == [("enter", "serve:chunk"), ("enter", "serve:readback"),
+                       ("exit", "serve:readback"), ("exit", "serve:chunk")]
+
+
+class TestSchedulerSpans:
+    def test_untraced_scheduler_enters_no_annotation(self, monkeypatch):
+        """telemetry=None: no span and no profiler annotation is entered
+        anywhere in the serving loop or the engine."""
+        def refuse(*a, **kw):
+            raise AssertionError("TraceAnnotation entered untraced")
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+        reqs = _oc_reqs()
+        _serve_oc(reqs, None, swap=True)
+        for r in reqs:
+            assert r.tokens_out == _golden(r.prompt, 12)
+        with pytest.raises(AssertionError, match="untraced"):
+            _serve_oc(_oc_reqs(), ServeTelemetry.create(trace=True))
+
+    @pytest.mark.parametrize("swap", [False, True])
+    def test_spans_count_what_the_loop_did(self, swap, monkeypatch):
+        """Each model-call span holds a dispatch and a readback span and is
+        followed by one emit and one retirement span; every seating
+        (resumes included) happens inside an admission span; the table
+        spans count every re-upload of a dirty block table."""
+        from repro.runtime import block_pool
+        flips = []
+
+        def set_dirty(self, v):
+            if getattr(self, "_dirty", False) and not v:
+                flips.append(1)
+            self._dirty = v
+
+        monkeypatch.setattr(block_pool.BlockPool, "dirty", property(
+            lambda self: self._dirty, set_dirty), raising=False)
+        tel = ServeTelemetry.create(trace=True)
+        reqs = _oc_reqs()
+        stats = _serve_oc(reqs, tel, swap=swap)
+        ev = tel.tracer.events
+        calls = [e for e in ev if e.name in ("chunk", "decode_batch")]
+        assert len(calls) == stats.prefill_calls + stats.decode_steps
+        for c in calls:
+            kids = sorted(e.name for e in ev if e.parent == c.sid)
+            assert kids == ["dispatch", "readback"], kids
+        for name in ("emit", "retirement"):
+            assert sum(e.name == name for e in ev) == len(calls), name
+        admissions = [(e.ts, e.ts + e.dur) for e in ev
+                      if e.name == "admission"]
+        seated = [e.ts for e in ev if e.name in ("admit", "resume")
+                  and e.dur == 0.0]
+        assert len(seated) >= len(reqs)
+        for t in seated:
+            assert any(a <= t <= b for a, b in admissions), t
+        assert sum(e.args["table_uploads"] for e in ev
+                   if e.name == "table") == len(flips) > 0
+
+    def test_spans_land_in_a_profiler_trace(self, tmp_path):
+        """Traced serving under ``jax.profiler``: every span appears in the
+        profile as a ``serve:<name>`` host event on the profiler's clock,
+        each read-back inside a model-call phase."""
+        from jax.profiler import ProfileData
+        tel = ServeTelemetry.create(trace=True)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            _serve_oc(_oc_reqs(), tel)
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = tmp_path.glob("**/*.xplane.pb")
+        spans = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+                 for plane in ProfileData.from_file(str(path)).planes
+                 for line in plane.lines for ev in line.events
+                 if ev.name.startswith("serve:")]
+        names = {n for n, _, _ in spans}
+        assert names == {"serve:" + e.name for e in tel.tracer.events
+                         if e.sid is not None}
+        calls = [(a, b) for n, a, b in spans
+                 if n in ("serve:chunk", "serve:decode_batch")]
+        backs = [(a, b) for n, a, b in spans if n == "serve:readback"]
+        assert len(backs) == len(calls) > 0
+        for a, b in backs:
+            assert any(ca <= a and b <= cb for ca, cb in calls)

@@ -16,6 +16,7 @@ that runs this file should.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -49,11 +50,23 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", prev)
 
 
+# a kernel's custom call: its HLO name and its op_name metadata
+KERNEL_CALL = re.compile(r'%([\w-]+?)(?:\.\d+)? = [^\n]*custom_call_target='
+                         r'"tpu_custom_call"[^\n]*op_name="([^"]*)"')
+
+
 def _compile(sharding, fn, *shapes):
     args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding)
             for s, dt in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # every kernel carries its name (pallas_call name=), the one its HLO
+    # op is named by and the benchmark's trace readers match
+    calls = KERNEL_CALL.findall(text)
+    assert calls
+    for hlo, path in calls:
+        assert path.endswith(f"/{hlo}/pallas_call"), (hlo, path)
 
 
 # ---------------------------------------------------------------------------
