@@ -31,15 +31,17 @@ Module map (which kernel serves which paper equation):
                    (``paged_attend_decode`` bf16/f32,
                    ``paged_int8_attend_decode`` int8 with the same Fig.-1
                    site treatment / eq.-(3)-style zero-point corrections as
-                   int8_attend_decode). The grid walks each lane's logical
-                   blocks; the block table rides as a scalar-prefetch
-                   operand so every K/V DMA targets the lane's *physical*
-                   arena block, and cell validity is DERIVED from (logical
-                   index, q_pos) — stale cells of reallocated blocks are
-                   unreadable by construction. This is the deployment
-                   payoff squared: int8 halves bytes per token, paging
-                   makes bytes proportional to live tokens
-                   (runtime/block_pool.py, BENCH_serving.json paged rows).
+                   int8_attend_decode). Each lane walks only the pages it
+                   has written (``walk_blocks``), several pages a compute
+                   block (128 tokens of a bf16/f32 arena, one page of an
+                   int8 arena); the block table rides as a scalar-prefetch
+                   operand so each page's double-buffered copy targets the
+                   lane's *physical* arena page, and cell validity is
+                   DERIVED from (logical index, q_pos) — stale cells of
+                   reallocated pages are unreadable by construction. This
+                   is the deployment payoff squared: int8 halves bytes per
+                   token, paging makes bytes and work proportional to live
+                   tokens (runtime/block_pool.py).
 
 Simulate vs deploy: the ``*_fake_quant`` variants back ``Mode.APPLY`` / QAT
 (f32 in, f32 out — quantization error only); the emitting variants back

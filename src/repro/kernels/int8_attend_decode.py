@@ -107,21 +107,22 @@ def merge_parts(out, hd: int):
     return jnp.concatenate([out[:, :, 0], out[:, :, 1]], axis=-1)[..., :hd]
 
 
-def decode_attend_step(*, step, n_blocks: int, lane, valid, q_ref, k_ref,
-                       v_ref, o_ref, m_ref, l_ref, acc_ref, hd: int,
+def decode_attend_step(*, step, n_blocks, lane, valid, q_ref, k_refs,
+                       v_refs, o_ref, m_ref, l_ref, acc_ref, hd: int,
                        quantized: bool, kv_bits: int,
                        logit_softcap: Optional[float], smq_ref, smo_ref,
                        sm_qmin: int, sm_qmax: int, smo_qmin: int,
                        smo_qmax: int, qs_ref=None, qz_ref=None, kz_ref=None,
                        vz_ref=None, ks_ref=None, vs_ref=None):
-    """One grid step of online-softmax decode attention over one K/V block,
-    for every kv head of one lane — shared by the dense and paged kernels.
+    """One step of online-softmax decode attention over one K/V block, for
+    every kv head of one lane — shared by the dense and paged kernels.
 
     ``valid``: (1, C) bool mask of this block's cells. Blocks: q (1, KV,
-    parts, G, w); k/v (1, C, KV, w); scales (1, KV, C); q scales / zps
-    (1, KV, G, 1); kz/vz (B, KV) SMEM. Scratch: m/l (KV, G, 1), acc
-    (KV * parts, G, w). ``step`` walks ``n_blocks`` (``2 * n_blocks`` with
-    the two-pass softmax_out schedule)."""
+    parts, G, w); k/v: the block's pages in order, each (1, c, KV, w), C
+    cells in all; scales (1, KV, C); q scales / zps (1, KV, G, 1); kz/vz
+    (B, KV) SMEM. Scratch: m/l (KV, G, 1), acc (KV * parts, G, w).
+    ``step`` walks ``n_blocks`` (``2 * n_blocks`` with the two-pass
+    softmax_out schedule); both may be traced."""
     kv, parts = q_ref.shape[1], q_ref.shape[2]
     has_smo = smo_ref is not None
 
@@ -134,10 +135,15 @@ def decode_attend_step(*, step, n_blocks: int, lane, valid, q_ref, k_ref,
     def _parts(x):
         return list(unpack_halves(x)) if kv_bits == 4 else [x]
 
+    def _cells(refs, h):
+        """(C, w) cells of kv head h over the block's pages."""
+        rows = [r[0, :, h, :] for r in refs]
+        return rows[0] if len(rows) == 1 else jnp.concatenate(rows, axis=0)
+
     for h in range(kv):
         qs = [q_ref[0, h, i] for i in range(parts)]             # (G, w)
         if quantized:
-            ks = _parts(k_ref[0, :, h, :])                       # (C, w) int8
+            ks = _parts(_cells(k_refs, h))                      # (C, w) int8
             s32 = sum(_dot_t(q, k, jnp.int32) for q, k in zip(qs, ks))
             # zero-point corrections (asymmetric q grid / static per-head k
             # grid):  sum (q - zq)(k - zk)
@@ -157,7 +163,7 @@ def decode_attend_step(*, step, n_blocks: int, lane, valid, q_ref, k_ref,
             s = acc32 * qs_ref[0, h] * ks_ref[0, h:h + 1, :]     # (G, C)
         else:
             s = _dot_t(qs[0].astype(jnp.float32),
-                       k_ref[0, :, h, :].astype(jnp.float32), jnp.float32)
+                       _cells(k_refs, h).astype(jnp.float32), jnp.float32)
         if logit_softcap is not None:
             s = logit_softcap * jnp.tanh(s / logit_softcap)
         if smq_ref is not None:
@@ -175,7 +181,7 @@ def decode_attend_step(*, step, n_blocks: int, lane, valid, q_ref, k_ref,
                 pmat = pmat * vs_ref[0, h:h + 1, :]
                 zcorr = vz_ref[lane, h] * jnp.sum(pmat, axis=-1,
                                                   keepdims=True)
-            for i, v in enumerate(_parts(v_ref[0, :, h, :])):
+            for i, v in enumerate(_parts(_cells(v_refs, h))):
                 pv = _dot(pmat, v.astype(jnp.float32))
                 if quantized:
                     pv = pv - zcorr
@@ -244,8 +250,8 @@ def _attend_decode_kernel(*refs, n_chunks: int, hd: int,
         valid &= kp > qp - window
     decode_attend_step(
         step=pl.program_id(1), n_blocks=n_chunks, lane=lane, valid=valid,
-        q_ref=q_ref, k_ref=k_ref, v_ref=v_ref, o_ref=o_ref, m_ref=m_ref,
-        l_ref=l_ref, acc_ref=acc_ref, hd=hd, quantized=True,
+        q_ref=q_ref, k_refs=[k_ref], v_refs=[v_ref], o_ref=o_ref,
+        m_ref=m_ref, l_ref=l_ref, acc_ref=acc_ref, hd=hd, quantized=True,
         kv_bits=kv_bits, logit_softcap=logit_softcap, smq_ref=smq_ref,
         smo_ref=smo_ref, sm_qmin=sm_qmin, sm_qmax=sm_qmax,
         smo_qmin=smo_qmin, smo_qmax=smo_qmax, qs_ref=qs_ref, qz_ref=qz_ref,

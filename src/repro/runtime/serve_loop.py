@@ -71,6 +71,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.paged_attend_decode import pages_per_block, walk_blocks
 from repro.runtime.block_pool import BlockPool, blocks_for_tokens
 from repro.runtime.engine import DecodeState, Engine
 from repro.runtime.radix_cache import RadixCache
@@ -1001,20 +1002,33 @@ class Scheduler:
         return self._tracer.span(name, self._book.step, **args)
 
     def _step_call(self, phase: str, op: Callable, args,
-                   n_lanes: Optional[int] = None):
+                   n_lanes: Optional[int] = None, **counters):
         """One engine op (a jitted model call plus greedy readback). Under
         tracing it becomes a phase span holding the engine's dispatch and
         readback spans — the readback's host-side token conversion blocks
         on device execution, so the duration covers the computation, not
-        just dispatch. Telemetry unwrapping happens inside the engine
-        (telemetry_sink)."""
+        just dispatch; ``counters`` become span args. Telemetry unwrapping
+        happens inside the engine (telemetry_sink)."""
         if self._tracer is None:
             return op(*args)
         with self._tracer.phase(phase, self._book.step) as ph:
             toks, cache = op(*args)
             if n_lanes is not None:
                 ph.args["lanes"] = n_lanes
+            ph.args.update(counters)
         return toks, cache
+
+    def _attend_blocks(self, state: DecodeState) -> int:
+        """Compute blocks the paged decode attention walks this step in
+        one layer of the widest span, summed over lanes — the kernel's own
+        count (kernels/paged_attend_decode.py::walk_blocks) at the lanes'
+        positions (-1 for a lane the step does not decode)."""
+        bs = self.pool.block_size
+        quantized = any(x.dtype == jnp.int8
+                        for x in jax.tree.leaves(state.cache))
+        return int(np.sum(walk_blocks(
+            np.asarray(state.pos)[:, 0], nb=-(-self._write_caps[-1] // bs),
+            bs=bs, pages=pages_per_block(bs, quantized))))
 
     def _timed(self, phase: str, thunk: Callable, **args):
         """Time a host-side phase (block swap in/out) as a duration event."""
@@ -1510,10 +1524,13 @@ class Scheduler:
             return DecodeState(state.tokens, state.pos, cache)
         with self._span("inputs"):
             tokens, pos = state.tokens.copy(), state.pos.copy()
+        step_state = DecodeState(state.tokens, state.pos, cache)
+        counters = ({"attend_blocks": self._attend_blocks(step_state)}
+                    if self._tracer is not None and self.pool is not None
+                    else {})
         nxt, cache = self._step_call(
-            "decode_batch", self.engine.generate,
-            (DecodeState(state.tokens, state.pos, cache),),
-            n_lanes=len(active))
+            "decode_batch", self.engine.generate, (step_state,),
+            n_lanes=len(active), **counters)
         book.count_decode(len(active))
         book.step += 1
         with self._span("emit"):

@@ -20,6 +20,7 @@ from repro.models import transformer as tfm
 from repro.runtime import BlockPool, Request, serve
 from repro.runtime.steps import (make_admit_step, make_decode_step,
                                  make_prefill_step)
+from paged_testlib import int8_walk_case
 
 pytestmark = pytest.mark.lowbit
 
@@ -207,6 +208,10 @@ class TestInt4AttendKernel:
             s_cap=S, k_zp=jnp.asarray(kz), v_zp=jnp.asarray(vz), kv_bits=4)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-4, atol=2e-4)
+        # lanes at every edge of the live-bounded walk, stale pages past
+        # each live bound, global and wrapped ring layers, both schedules
+        int8_walk_case(None, None, False, "global", kv_bits=4)
+        int8_walk_case(200, 30.0, True, "ring", kv_bits=4)
 
 
 @pytest.mark.deploy

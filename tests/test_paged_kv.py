@@ -8,7 +8,8 @@ Layers of coverage (mirroring tests/test_kv_quant.py + test_scheduler.py):
   within reservation, free-list accounting (leak check).
 * Kernel-vs-oracle for ``paged_attend_decode`` and
   ``paged_int8_attend_decode`` across window / softcap / GQA / partially
-  mapped lanes / idle lanes / in-kernel softmax sites.
+  mapped lanes / idle lanes / in-kernel softmax sites, and lanes at every
+  edge of the live-bounded multi-page walk (tests/paged_testlib.py).
 * Write-path + derived-position properties: stored positions equal derived
   positions on every written cell, and a reallocated block's STALE cells
   are never readable (allocation order, not memset, provides isolation).
@@ -33,6 +34,7 @@ from repro.runtime import (BlockPool, Request, blocks_for_tokens, serve,
                            serve_continuous)
 from repro.runtime.steps import (make_admit_step, make_decode_step,
                                  make_prefill_step)
+from paged_testlib import check_lanes, int8_walk_case, walk_operands
 from serve_testlib import golden as _golden
 from serve_testlib import next_arr as _next_arr
 from serve_testlib import onehot as _onehot
@@ -117,17 +119,52 @@ def _paged_operands(key, N=10, bs=8, KV=2, G=2, hd=16, s_cap=40, B=3):
 
 
 class TestPagedKernelVsOracle:
-    @pytest.mark.parametrize("window,softcap", [
-        (None, None), (16, None), (None, 50.0), (8, 30.0)])
-    def test_bf16_matches_ref(self, window, softcap):
-        q, k_a, v_a, tbl, q_pos = _paged_operands(jax.random.PRNGKey(0))
-        got = ops.paged_attend_decode(q, k_a, v_a, tbl, q_pos, s_cap=40,
-                                      window=window, logit_softcap=softcap)
-        want = ref.paged_attend_decode_ref(q, k_a, v_a, tbl, q_pos,
-                                           s_cap=40, window=window,
-                                           logit_softcap=softcap)
-        np.testing.assert_allclose(np.asarray(got)[:2], np.asarray(want)[:2],
-                                   rtol=3e-5, atol=3e-5)
+    @pytest.mark.parametrize("window,softcap,walk,cache_dtype,sites", [
+        pytest.param(None, None, None, None, False, id="None-None"),
+        pytest.param(16, None, None, None, False, id="16-None"),
+        pytest.param(None, 50.0, None, None, False, id="None-50.0"),
+        pytest.param(8, 30.0, None, None, False, id="8-30.0"),
+        pytest.param(None, None, "global", jnp.float32, False,
+                     id="walk-f32"),
+        pytest.param(100, 30.0, "global", jnp.bfloat16, False,
+                     id="walk-bf16-window-softcap"),
+        pytest.param(None, None, "global", jnp.bfloat16, True,
+                     id="walk-bf16-sites"),
+        pytest.param(None, 50.0, "global", jnp.float32, True,
+                     id="walk-f32-sites"),
+        pytest.param(200, None, "ring", jnp.bfloat16, False,
+                     id="ring-bf16"),
+        pytest.param(200, None, "ring", jnp.float32, True,
+                     id="ring-f32-sites")])
+    def test_bf16_matches_ref(self, window, softcap, walk, cache_dtype,
+                              sites):
+        """Kernel == oracle. The walk cases mix lanes at every edge of the
+        live-bounded multi-page walk, with unmapped entries and stale
+        pages past each lane's live bound; ``sites`` adds softmax_in and
+        the two-pass softmax_out schedule."""
+        kw = dict(window=window, logit_softcap=softcap)
+        if sites:
+            kw.update(sm_quant=jnp.asarray([0.02, 100.0]),
+                      smo_quant=jnp.asarray([1.0 / 255.0, 0.0]))
+        if walk is None:
+            q, k_a, v_a, tbl, q_pos = _paged_operands(jax.random.PRNGKey(0))
+            got = ops.paged_attend_decode(q, k_a, v_a, tbl, q_pos, s_cap=40,
+                                          **kw)
+            want = ref.paged_attend_decode_ref(q, k_a, v_a, tbl, q_pos,
+                                               s_cap=40, **kw)
+            np.testing.assert_allclose(np.asarray(got)[:2],
+                                       np.asarray(want)[:2],
+                                       rtol=3e-5, atol=3e-5)
+            return
+        (k_a, v_a), tbl, q_pos, s_cap = walk_operands(
+            1, walk, cache_dtype=cache_dtype)
+        q = jax.random.normal(jax.random.PRNGKey(4), (len(q_pos), 2, 2, 16))
+        got = ops.paged_attend_decode(q, k_a, v_a, tbl, q_pos, s_cap=s_cap,
+                                      **kw)
+        want = ref.paged_attend_decode_ref(
+            q, k_a, v_a, ops._lane_blocks(tbl, s_cap, 8), q_pos,
+            s_cap=s_cap, **kw)
+        check_lanes(got, want, q_pos)
 
     def test_bf16_softmax_sites_in_kernel(self):
         """softmax_in (one-pass) and softmax_out (two-pass over the lane's
@@ -161,9 +198,17 @@ class TestPagedKernelVsOracle:
                                       np.asarray(got2)[:2])
 
     @pytest.mark.deploy
-    @pytest.mark.parametrize("window,softcap,sites", [
-        (None, None, False), (16, 50.0, False), (None, None, True)])
-    def test_int8_matches_ref(self, window, softcap, sites):
+    @pytest.mark.parametrize("window,softcap,sites,walk", [
+        pytest.param(None, None, False, None, id="None-None-False"),
+        pytest.param(16, 50.0, False, None, id="16-50.0-False"),
+        pytest.param(None, None, True, None, id="None-None-True"),
+        pytest.param(None, None, False, "global", id="walk"),
+        pytest.param(100, 30.0, True, "global", id="walk-sites"),
+        pytest.param(200, None, True, "ring", id="ring-sites")])
+    def test_int8_matches_ref(self, window, softcap, sites, walk):
+        if walk is not None:
+            int8_walk_case(window, softcap, sites, walk, kv_bits=8)
+            return
         key = jax.random.PRNGKey(3)
         ks = jax.random.split(key, 8)
         N, bs, KV, G, hd, B, s_cap = 10, 8, 2, 2, 16, 3, 40

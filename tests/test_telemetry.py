@@ -515,6 +515,47 @@ class TestSchedulerSpans:
         assert sum(e.args["table_uploads"] for e in ev
                    if e.name == "table") == len(flips) > 0
 
+    @pytest.mark.parametrize("cache_dtype", [jnp.float32, jnp.int8],
+                             ids=["float", "int8"])
+    def test_decode_spans_count_the_attention_walk(self, cache_dtype):
+        """Every ``decode_batch`` span carries ``attend_blocks``: the
+        paged decode walk's count (``walk_blocks``) at the positions that
+        step decoded, in one layer, summed over lanes — 128-token blocks
+        over a float cache, one page a block over an int8 one."""
+        from repro.kernels.paged_attend_decode import (pages_per_block,
+                                                       walk_blocks)
+        m = Stub()
+        seen = []
+
+        def decode(tokens, pos, cache):
+            seen.append(np.asarray(pos)[:, 0].copy())
+            return m.decode(tokens, pos, cache)
+
+        bs, nb = 4, 40
+        reqs = [Request(rid=i, prompt=np.full(n, 3 + i, np.int32),
+                        max_new_tokens=12)
+                for i, n in enumerate([130, 20, 7, 140])]
+        tel = ServeTelemetry.create(trace=True)
+        serve_continuous(
+            m.admit, decode,
+            lambda batch: {"kv": jnp.zeros((batch, 4), cache_dtype)},
+            reqs, batch_slots=2, block_pool=BlockPool(2 * nb, bs, 2, nb),
+            chunk_fn=m.chunk, prefill_chunk=32, telemetry=tel)
+        spans = [e for e in tel.tracer.events if e.name == "decode_batch"]
+        assert len(spans) == len(seen) > 0
+        pages = pages_per_block(bs, cache_dtype == jnp.int8)
+        assert pages == (1 if cache_dtype == jnp.int8 else 32)
+        multi = 0
+        for e, pos in zip(spans, seen):
+            assert e.args["attend_blocks"] == int(np.sum(walk_blocks(
+                pos, nb=nb, bs=bs, pages=pages)))
+            # the same walk counted by hand: live pages, then blocks
+            live = [min(nb, -(-(p + 1) // bs)) for p in pos if p >= 0]
+            assert e.args["attend_blocks"] == sum(-(-n // pages)
+                                                  for n in live)
+            multi += e.args["attend_blocks"] > len(live)
+        assert multi > 0        # some lane walked more than one block
+
     def test_spans_land_in_a_profiler_trace(self, tmp_path):
         """Traced serving under ``jax.profiler``: every span appears in the
         profile as a ``serve:<name>`` host event on the profiler's clock,

@@ -172,18 +172,24 @@ def test_int8_attend_decode(one_chip, kv, hd, kv_bits, two_pass):
              ((2,), f32))
 
 
-@pytest.mark.parametrize("kv", [8, 4])
-@pytest.mark.parametrize("cache_dtype", [bf16, f32], ids=["bf16", "f32"])
-def test_paged_attend_decode(one_chip, kv, cache_dtype):
+@pytest.mark.parametrize("kv,cache_dtype,lanes,nb", [
+    pytest.param(8, bf16, DECODE, NB, id="bf16-8"),
+    pytest.param(8, f32, DECODE, NB, id="f32-8"),
+    pytest.param(4, bf16, DECODE, NB, id="bf16-4"),
+    pytest.param(4, f32, DECODE, NB, id="f32-4"),
+    # the served cell danube3-4b-bf16.chat-16: 16 lanes, 152 pages of 16
+    # each, a bf16 arena of 2,432 pages
+    pytest.param(8, bf16, 16, 152, id="chat-16")])
+def test_paged_attend_decode(one_chip, kv, cache_dtype, lanes, nb):
     g = 32 // kv
 
     def fn(q, k, v, tbl, qp):
-        return ops.paged_attend_decode(q, k, v, tbl, qp, s_cap=S_LEN,
+        return ops.paged_attend_decode(q, k, v, tbl, qp, s_cap=nb * BS,
                                        window=4096, interpret=False)
-    _compile(one_chip, fn, ((DECODE, kv, g, HD), f32),
-             ((N_BLOCKS, BS, kv, HD), cache_dtype),
-             ((N_BLOCKS, BS, kv, HD), cache_dtype), ((DECODE, NB), i32),
-             ((DECODE,), i32))
+    _compile(one_chip, fn, ((lanes, kv, g, HD), f32),
+             ((lanes * nb, BS, kv, HD), cache_dtype),
+             ((lanes * nb, BS, kv, HD), cache_dtype), ((lanes, nb), i32),
+             ((lanes,), i32))
 
 
 @pytest.mark.parametrize("kv,kv_bits,two_pass", [
