@@ -156,8 +156,8 @@ def build_program(conf: dict, cp: dict, seed: int):
     from repro.launch.mesh import make_serving_mesh
     from repro.parallel import make_dist
 
-    cfg = bmodel.model_config(conf)
     m, prec = conf["model"], conf["precision"]
+    cfg = bmodel.arch(m).model_config(conf)
     dist = make_dist(make_serving_mesh(1))
     shardings = bmodel.weight_shardings(m, dist)
     params = bmodel.weights(m, seed, shardings)
@@ -401,7 +401,8 @@ def _measure(cell, seed, seconds, traced, setup, compiles, t_start, peaks,
     picked = sample_for_check(times, quotas, mix["clients"], seed,
                               chk["served_tokens"], chk["max_requests"],
                               [len(p) for p in prompts])
-    weights = bmodel.plain_view(m, bmodel.weights(m, seed, shardings))
+    weights = bmodel.arch(m).plain_view(m, bmodel.weights(m, seed,
+                                                         shardings))
     seqs = [(prompts[k], np.asarray(served[k])) for k in picked]
     gaps = gaps_against_reference(m, weights, seqs, cp["max_len"])
     if extra_check is not None:

@@ -11,11 +11,8 @@ from bench import costs
 def read(rec):
     if not rec.calls or rec.peaks is None:
         return None
-    new = keys = 0
-    for c in rec.calls:
-        live = c["pos"][c["pos"] >= 0]
-        new += live.size
-        keys += int(np.sum(live + 1))
-    ops = costs.model_ops(rec.model, new, keys, rec.tokens)
+    contexts = np.concatenate([c["pos"][c["pos"] >= 0] + 1
+                               for c in rec.calls])
+    ops = costs.model_ops(rec.model, contexts, rec.tokens)
     span = rec.window[1] - rec.window[0]
     return 100.0 * ops / (span * costs.compute_peak(rec.peaks, rec.path))

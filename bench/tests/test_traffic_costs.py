@@ -2,7 +2,7 @@
 import numpy as np
 import pytest
 
-from bench import costs, traffic
+from bench import costs, model as bmodel, traffic
 from bench.tests import tiny
 
 
@@ -43,9 +43,9 @@ def test_weight_bytes_equal_what_the_packing_leaves():
     c = tiny.cell()
     prog = harness.build_program(c["conf"], c["params"], 3)
     got = sum(x.nbytes for x in jax.tree.leaves(prog.params))
-    assert got == costs.packed_weight_bytes(c["conf"]["model"],
-                                            c["conf"]["precision"]
-                                            ["peg_groups"])
+    m = c["conf"]["model"]
+    assert got == bmodel.arch(m).packed_weight_bytes(
+        m, c["conf"]["precision"]["peg_groups"])
 
 
 def test_unknown_device_has_no_peaks():
@@ -70,11 +70,10 @@ def test_kv_token_bytes_match_the_program_arenas(kv_bits):
     scales and positions), at toy widths."""
     import jax
     import jax.numpy as jnp
-    from bench import model as bmodel
     from repro.models import transformer as tfm
     m = tiny.MODEL
-    cfg = bmodel.model_config({"name": "tiny", "source": "test",
-                               "model": m})
+    cfg = bmodel.arch(m).model_config({"name": "tiny", "source": "test",
+                                       "model": m})
     blocks, bs = 8, 4
     cache = tfm.init_cache(cfg, 2, 16, dtype=jnp.bfloat16, kv_bits=kv_bits,
                            paged=True, block_size=bs, num_blocks=blocks,
@@ -84,5 +83,6 @@ def test_kv_token_bytes_match_the_program_arenas(kv_bits):
     if kv_bits == 16:       # the bf16 kernel reads k and v, not positions
         arenas -= sum(x.nbytes for x in jax.tree.leaves(cache)
                       if x.dtype == jnp.int32)
-    assert arenas == costs.kv_token_bytes(m, kv_bits) * blocks * bs \
-        * m["num_layers"]
+    assert arenas == blocks * bs * sum(
+        costs.kv_token_bytes(layer, kv_bits)
+        for layer in bmodel.arch(m).layers(m))

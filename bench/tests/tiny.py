@@ -7,10 +7,10 @@ from pathlib import Path
 
 ROOT = Path(__file__).resolve().parents[2]
 
-MODEL = {"num_layers": 2, "d_model": 64, "num_heads": 4, "num_kv_heads": 2,
-         "head_dim": 16, "d_ff": 128, "vocab_size": 128, "window": None,
-         "rope_theta": 10000.0, "rms_norm_eps": 1e-6,
-         "tie_embeddings": False}
+MODEL = {"architecture": "llama", "num_layers": 2, "d_model": 64,
+         "num_heads": 4, "num_kv_heads": 2, "head_dim": 16, "d_ff": 128,
+         "vocab_size": 128, "window": None, "rope_theta": 10000.0,
+         "rms_norm_eps": 1e-6, "tie_embeddings": False}
 
 PRECISION = {"w8a8": {"path": "w8a8", "peg_groups": 4, "kv_bits": 8},
              "bf16": {"path": "bf16", "kv_bits": 16}}
